@@ -100,7 +100,7 @@ KNOWN_ENTRY_POINTS: Tuple[KnownEntry, ...] = (
     # paged decode/verify attention kernel (reached from gqa_forward's
     # paged extend branch; scale/logit_cap fold into the kernel closure)
     KnownEntry("kernels/decode_attention/ops.py", "paged_decode_attention",
-               static=("scale", "logit_cap", "interpret")),
+               static=("scale", "logit_cap", "interpret", "mesh")),
     # numerical sentinel (serving/faults.py) — runs inside the jitted
     # verify stage on the raw logits every round
     KnownEntry("serving/faults.py", "logits_finite"),

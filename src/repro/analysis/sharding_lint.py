@@ -42,7 +42,7 @@ from repro.analysis._astutil import (FuncInfo, ModuleInfo, Project,
 from repro.analysis.findings import Finding
 from repro.analysis.registry import KNOWN_MESH_AXES
 
-_SHARD_MAP_NAMES = ("shard_map", "jax.experimental.shard_map.shard_map",
+_SHARD_MAP_NAMES = ("shard_map", "jax.shard_map",
                     "shmap")
 _PARTIAL_NAMES = ("functools.partial", "partial")
 

@@ -20,7 +20,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core.analytics import sigma_from_alpha
-from repro.core.simulator import Simulator
+from repro.core.simulator import Simulator, hardware_for
 
 
 @dataclass
@@ -34,7 +34,9 @@ class AutoTuner:
 
     def __post_init__(self):
         if self.sim is None:
-            self.sim = Simulator()
+            # price the device that serves (hardware_for fails on an
+            # unknown TPU instead of assuming a v5e)
+            self.sim = Simulator(hw=hardware_for())
 
     def speedup(self, batch: int, gamma: int, alpha: Optional[float] = None) -> float:
         a = self.alpha if alpha is None else alpha

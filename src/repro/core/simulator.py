@@ -48,6 +48,33 @@ class Hardware:
 
 V5E = Hardware()
 
+#: Per-chip peaks of the devices the tuner can price, keyed by
+#: ``jax.Device.device_kind``.  TPU v5e ("TPU v5 lite"): Google Cloud
+#: documentation, "TPU v5e" — 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s,
+#: 1,600 Gbit/s of chip-to-chip interconnect (4 links of 50 GB/s).
+HARDWARE_BY_DEVICE_KIND = {"TPU v5 lite": V5E}
+
+
+def hardware_for(device=None) -> Hardware:
+    """The hardware model for ``device`` (default: the first JAX device).
+
+    A TPU is looked up by its ``device_kind``; a kind missing from
+    :data:`HARDWARE_BY_DEVICE_KIND` is an error rather than a silent v5e.
+    The CPU (tests, interpret-mode kernels) has no peaks of its own worth
+    tuning against and keeps the explicit v5e model.
+    """
+    if device is None:
+        import jax
+        device = jax.devices()[0]
+    if device.platform == "cpu":
+        return V5E
+    hw = HARDWARE_BY_DEVICE_KIND.get(device.device_kind)
+    if hw is None:
+        raise ValueError(
+            f"no hardware model for device kind {device.device_kind!r} "
+            f"({device.platform}); known: {sorted(HARDWARE_BY_DEVICE_KIND)}")
+    return hw
+
 
 def _component_time(flops: float, bytes_: float, hw: Hardware) -> float:
     n = max(hw.num_chips, 1)

@@ -663,21 +663,22 @@ class SDEngine:
             (t_cache, p_state, last_token, committed, n_commit, n_acc,
              finite, pf) = fn(params, state.t_cache, state.p_state,
                               state.last_token, active, k_prop, k_rej)
-        committed = np.asarray(committed)            # device sync
-        n_commit_np = np.asarray(n_commit)
+        # the round's one device sync: an explicit fetch of everything the
+        # host reads (np.asarray on each would be four implicit transfers)
+        committed, n_commit_np, n_acc, finite, pf = jax.device_get(
+            (committed, n_commit, n_acc, finite, pf))
         round_time = time.perf_counter() - t_round
         pf_counts = None
         if pf is not None:
-            pf_counts = {k: int(np.asarray(pf[k]))
-                         for k in ("hits", "actual", "predicted")}
+            pf_counts = {k: int(pf[k]) for k in ("hits", "actual", "predicted")}
         new_state = replace(state, t_cache=t_cache, p_state=p_state,
                             last_token=last_token)
         result = RoundResult(
             committed=committed, n_commit=n_commit_np,
-            n_accept=np.asarray(n_acc), width=committed.shape[1] - 1,
+            n_accept=n_acc, width=committed.shape[1] - 1,
             gamma=gamma, pf=pf_counts, round_time=round_time,
             phase_times=phases if timed else None,
-            finite=np.asarray(finite))
+            finite=finite)
         return new_state, result
 
     # -------------------------------------------------------------- admission

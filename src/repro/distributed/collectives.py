@@ -39,10 +39,10 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed.constraints import resolve_mesh
+from repro.kernels import resolve_interpret
 
 
 def _act(x, activation):
@@ -142,18 +142,19 @@ def moe_ep_forward(params: dict, cfg, x: jnp.ndarray, *,
     sizes the per-destination slot buffers to the drop-free worst case
     N_loc·K, making outputs token-identical to the single-device gmm
     dispatch; a finite factor trades a2a volume for possible drops under
-    extreme skew.  Falls back to the dense one-hot path when no mesh is
-    threaded (single-device tests) or E does not divide over the model
-    axis.
+    extreme skew.  Needs a mesh with a ``"model"`` axis whose size
+    divides E; anything else raises (single-device serving uses the
+    ``gmm`` dispatch).
     """
     mesh, layout = resolve_mesh(mesh, layout)
-    if mesh is None or "model" not in mesh.axis_names \
-            or cfg.num_experts % mesh.shape["model"] != 0:
-        from repro.models import moe as moe_mod
-        return moe_mod.moe_forward(params, cfg, x, dispatch="onehot")[0]
-    if interpret is None:
-        from repro.kernels.gmm.ragged import INTERPRET
-        interpret = INTERPRET
+    if mesh is None or "model" not in mesh.axis_names:
+        raise ValueError("moe_dispatch='ep' needs a mesh with a 'model' "
+                         "axis; single-device serving uses 'gmm'")
+    if cfg.num_experts % mesh.shape["model"] != 0:
+        raise ValueError(
+            f"moe_dispatch='ep': {cfg.num_experts} experts do not divide "
+            f"over a model axis of {mesh.shape['model']}")
+    interpret = resolve_interpret(interpret)
 
     B, T, d = x.shape
     m = mesh.shape["model"]
@@ -183,12 +184,12 @@ def moe_ep_forward(params: dict, cfg, x: jnp.ndarray, *,
                 P("model", None, None), P("model", None, None),
                 P("model", None, None),            # expert slices
                 (P(), P(), P()) if has_shared else ())
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_ragged_ep_shard, cfg=cfg, slots=slots,
                 activation=cfg.mlp_activation, model_axis="model",
                 m_shards=m, interpret=interpret),
         mesh=mesh, in_specs=in_specs, out_specs=P(all_axes, None),
-        check_rep=False)
+        check_vma=False)
     y = fn(xf, params["router"], params["w_gate"], params["w_up"],
            params["w_down"], shared_w)
     return y[:B * T].reshape(B, T, d)

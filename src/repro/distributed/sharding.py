@@ -196,10 +196,10 @@ def cache_spec(path_str: str, shape, *, mesh: Mesh, kv_mode: str = "auto") -> P:
         # (B, max_pages) logical→physical block table: rows follow batch
         return _fit(mesh, P(d_axes, None), shape)
     if re.search(r"(k|v)_pages$", path_str) and len(shape) == 5:
-        # (P, pool, page, Hkv, hd) physical page pool: pages are a SHARED
+        # (P, pool, Hkv, page, hd) head-major page pool: pages are a SHARED
         # pool addressed through the table (page ids carry no batch
         # locality), so only the head axis shards — over "model"
-        return _fit(mesh, P(None, None, None, "model", None), shape)
+        return _fit(mesh, P(None, None, "model", None, None), shape)
     if re.search(r"(latent|k_rope)_pages$", path_str):
         # (P, pool, page, dim) MLA page pools: replicated pool
         return P(*([None] * len(shape)))

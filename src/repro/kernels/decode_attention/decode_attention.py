@@ -83,59 +83,22 @@ def _paged_decode_kernel(
     *,
     ps: int, mp: int, t_pad: int, t_real: int, scale: float, logit_cap: float,
 ):
-    del tbl_ref  # consumed by the K/V index maps, not the kernel body
-    b, j = pl.program_id(0), pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    length = len_ref[b]
-    rows = q_ref.shape[2]                                  # g * t_pad
-    # query position per row: length + (row % t_pad), capped by t_real
-    row_t = jax.lax.broadcasted_iota(jnp.int32, (rows, ps), 0) % t_pad
-    q_pos = length + row_t
-    # logical KV position of this page's slots; physical placement is
-    # resolved by the block-table index map, the mask only sees logical
-    k_pos = j * ps + jax.lax.broadcasted_iota(jnp.int32, (rows, ps), 1)
-    valid = (k_pos <= q_pos) & (row_t < t_real)
-
-    def _step():
-        q = q_ref[0, 0].astype(jnp.float32)                # (rows, d)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)          # (ps, d)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if logit_cap > 0:
-            s = jnp.tanh(s / logit_cap) * logit_cap
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        m_ref[...] = m_new
-        v = v_ref[0, :, 0, :].astype(jnp.float32)          # (ps, d)
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-
-    # skip logical pages entirely beyond the newest query position;
-    # unallocated table entries point at the trash page but are never
-    # reached because their logical position exceeds length + t_real - 1
-    pl.when(j * ps <= length + t_real - 1)(_step)
-
-    @pl.when(j == mp - 1)
-    def _emit():
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+    """The dense kernel body, one page per KV grid step.  The block table
+    is consumed by the K/V index maps: the body only sees logical
+    positions, so unallocated entries (the trash page) sit beyond
+    ``length + t_real - 1`` and are skipped or masked like any other
+    position past the row's length."""
+    del tbl_ref
+    _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
+                   bk=ps, nk=mp, t_pad=t_pad, t_real=t_real, scale=scale,
+                   logit_cap=logit_cap)
 
 
 @functools.partial(
     jax.jit, static_argnames=("scale", "logit_cap", "interpret"))
 def paged_decode_attention_bhtd(
     q: jnp.ndarray,            # (B, Hq, T, D), T = gamma+1 fresh queries
-    k_pages: jnp.ndarray,      # (NP, ps, Hkv, D) physical page pool
+    k_pages: jnp.ndarray,      # (NP, Hkv, ps, D) head-major page pool
     v_pages: jnp.ndarray,
     lengths: jnp.ndarray,      # (B,) committed lengths (queries at length+t)
     table: jnp.ndarray,        # (B, MP) logical page -> physical page
@@ -146,6 +109,12 @@ def paged_decode_attention_bhtd(
 ) -> jnp.ndarray:
     """Decode/verify attention reading KV straight from the paged pool.
 
+    The pool is head-major so one (page, head) block is a contiguous
+    ``(ps, D)`` tile: its last two block dimensions meet the TPU's
+    (sublane, lane) tiling for any ``Hkv``.  (A ``(ps, Hkv, D)`` pool would
+    need a block of one head out of ``Hkv`` in the sublane dimension,
+    which the compiler refuses unless ``Hkv`` is a multiple of 8.)
+
     Walks the block table inside the Pallas grid: the K/V index maps look
     the physical page id up in the scalar-prefetched flattened ``table``
     (the ``kernels/gmm/ragged.py`` idiom), so no dense ``pool[table]``
@@ -154,7 +123,7 @@ def paged_decode_attention_bhtd(
     walked one page per grid step instead of one ``bk`` block.
     """
     B, Hq, T, D = q.shape
-    NP, ps, Hkv, _ = k_pages.shape
+    NP, Hkv, ps, _ = k_pages.shape
     MP = table.shape[1]
     g = Hq // Hkv
     if scale == 0.0:
@@ -176,10 +145,10 @@ def paged_decode_attention_bhtd(
             in_specs=[
                 pl.BlockSpec((1, 1, rows, D),
                              lambda b, h, j, lens, tbl: (b, h, 0, 0)),
-                pl.BlockSpec((1, ps, 1, D),
-                             lambda b, h, j, lens, tbl: (tbl[b * MP + j], 0, h, 0)),
-                pl.BlockSpec((1, ps, 1, D),
-                             lambda b, h, j, lens, tbl: (tbl[b * MP + j], 0, h, 0)),
+                pl.BlockSpec((1, 1, ps, D),
+                             lambda b, h, j, lens, tbl: (tbl[b * MP + j], h, 0, 0)),
+                pl.BlockSpec((1, 1, ps, D),
+                             lambda b, h, j, lens, tbl: (tbl[b * MP + j], h, 0, 0)),
             ],
             out_specs=pl.BlockSpec((1, 1, rows, D),
                                    lambda b, h, j, lens, tbl: (b, h, 0, 0)),
@@ -277,8 +246,8 @@ def _selfcheck() -> None:
             for lp in range((int(lengths[b]) + T + ps - 1) // ps):
                 table[b, lp] = nxt
                 nxt += 1
-        k_pages = rng.standard_normal((NP, ps, Hkv, D)).astype(np.float32)
-        v_pages = rng.standard_normal((NP, ps, Hkv, D)).astype(np.float32)
+        k_pages = rng.standard_normal((NP, Hkv, ps, D)).astype(np.float32)
+        v_pages = rng.standard_normal((NP, Hkv, ps, D)).astype(np.float32)
         q = rng.standard_normal((B, Hq, T, D)).astype(np.float32)
         got = paged_decode_attention_bhtd(
             q, k_pages, v_pages, lengths, table, logit_cap=cap,
@@ -287,8 +256,8 @@ def _selfcheck() -> None:
             q, k_pages, v_pages, lengths, table, logit_cap=cap)
         np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
         # paged oracle == dense oracle on the gathered view
-        kd = k_pages[table].reshape(B, MP * ps, Hkv, D).transpose(0, 2, 1, 3)
-        vd = v_pages[table].reshape(B, MP * ps, Hkv, D).transpose(0, 2, 1, 3)
+        kd = k_pages[table].transpose(0, 2, 1, 3, 4).reshape(B, Hkv, MP * ps, D)
+        vd = v_pages[table].transpose(0, 2, 1, 3, 4).reshape(B, Hkv, MP * ps, D)
         dense = decode_attention_ref(q, kd, vd, lengths, logit_cap=cap)
         np.testing.assert_allclose(want, dense, rtol=2e-5, atol=2e-5)
         print(f"paged_decode_attention ps={ps} MP={MP} B={B} "

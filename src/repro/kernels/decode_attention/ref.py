@@ -39,7 +39,7 @@ def decode_attention_ref(
 
 def paged_decode_attention_ref(
     q: jnp.ndarray,            # (B, Hq, T, D)
-    k_pages: jnp.ndarray,      # (NP, ps, Hkv, D) physical page pool
+    k_pages: jnp.ndarray,      # (NP, Hkv, ps, D) head-major page pool
     v_pages: jnp.ndarray,
     lengths: jnp.ndarray,      # (B,)
     table: jnp.ndarray,        # (B, MP) logical page -> physical page
@@ -52,11 +52,11 @@ def paged_decode_attention_ref(
     Logical positions beyond ``length + T - 1`` are masked there, so trash
     or stale page contents never reach the softmax."""
     B, MP = table.shape
-    ps = k_pages.shape[1]
+    _, Hkv, ps, D = k_pages.shape
 
     def view(pool):
-        g = jnp.asarray(pool)[jnp.asarray(table)]           # (B, MP, ps, Hkv, D)
-        return g.reshape((B, MP * ps) + pool.shape[2:]).transpose(0, 2, 1, 3)
+        g = jnp.asarray(pool)[jnp.asarray(table)]           # (B, MP, Hkv, ps, D)
+        return g.transpose(0, 2, 1, 3, 4).reshape(B, Hkv, MP * ps, D)
 
     return decode_attention_ref(
         q, view(k_pages), view(v_pages), lengths,

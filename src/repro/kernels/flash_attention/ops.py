@@ -1,12 +1,12 @@
 """Public jit wrapper: (B, T, H, D) layout used by the model code."""
 from __future__ import annotations
 
-import jax
+from typing import Optional
+
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.flash_attention.flash_attention import flash_attention_bhtd
-
-INTERPRET = jax.default_backend() != "tpu"
 
 
 def flash_attention(
@@ -18,10 +18,10 @@ def flash_attention(
     window: int = 0,
     scale: float = 0.0,
     logit_cap: float = 0.0,
-    interpret: bool = INTERPRET,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     out = flash_attention_bhtd(
         q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
         causal=causal, window=window, scale=scale, logit_cap=logit_cap,
-        interpret=interpret)
+        interpret=resolve_interpret(interpret))
     return out.transpose(0, 2, 1, 3)

@@ -20,8 +20,9 @@ from typing import Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.gmm.gmm import gmm_capacity
-from repro.kernels.gmm.ragged import (INTERPRET, fused_gate_up,  # noqa: F401
+from repro.kernels.gmm.ragged import (fused_gate_up,  # noqa: F401
                                       make_group_metadata, ragged_gmm,
                                       ragged_moe_ffn)
 from repro.kernels.gmm.ref import combine_ref, dispatch_ref
@@ -71,7 +72,7 @@ def moe_ffn_gmm(
     *,
     capacity: int,
     activation: str = "silu",
-    interpret: bool = INTERPRET,
+    interpret: Optional[bool] = None,
     return_dropped: bool = False,
 ) -> Union[jnp.ndarray, Tuple[jnp.ndarray, jnp.ndarray]]:
     """Capacity-binned MoE FFN: dispatch → 3 grouped GEMMs → combine.
@@ -90,8 +91,9 @@ def moe_ffn_gmm(
         Static per-expert bin size (see :func:`expert_capacity`).
     activation : str
         "silu" (default) or "gelu".
-    interpret : bool
-        Run the Pallas kernels in interpret mode (CPU-correctness path).
+    interpret : bool, optional
+        Run the Pallas kernels in interpret mode; default: decided from
+        the backend (``repro.kernels.resolve_interpret``).
     return_dropped : bool
         Also return the number of (token, k) assignments that overflowed
         their expert's bin — deterministic (slot order) but no longer
@@ -105,6 +107,7 @@ def moe_ffn_gmm(
     """
     E, D, F = w_gate.shape
     N = x.shape[0]
+    interpret = resolve_interpret(interpret)
     bins, slot, kept = dispatch_ref(x, indices, E, capacity)
     h_gate = gmm_capacity(bins, w_gate, interpret=interpret)
     h_up = gmm_capacity(bins, w_up, interpret=interpret)
@@ -118,7 +121,7 @@ def moe_ffn_gmm(
 
 
 def gmm(xs: jnp.ndarray, w: jnp.ndarray, group_sizes: jnp.ndarray,
-        *, interpret: bool = INTERPRET) -> jnp.ndarray:
+        *, interpret: Optional[bool] = None) -> jnp.ndarray:
     """Sorted-token grouped matmul via the ragged Pallas kernel.
 
     Parameters
@@ -129,8 +132,9 @@ def gmm(xs: jnp.ndarray, w: jnp.ndarray, group_sizes: jnp.ndarray,
         (E, D, F) per-expert weight matrices.
     group_sizes : jnp.ndarray
         (E,) tokens routed to each expert (sums to N_sorted).
-    interpret : bool
-        Run the kernel in interpret mode (CPU-correctness path).
+    interpret : bool, optional
+        Run the kernel in interpret mode; default: decided from the
+        backend (``repro.kernels.resolve_interpret``).
 
     Returns
     -------
@@ -149,7 +153,7 @@ def gmm(xs: jnp.ndarray, w: jnp.ndarray, group_sizes: jnp.ndarray,
 
 def gmm_legacy(xs: jnp.ndarray, w: jnp.ndarray, group_sizes: jnp.ndarray,
                *, capacity: Optional[int] = None,
-               interpret: bool = INTERPRET) -> jnp.ndarray:
+               interpret: Optional[bool] = None) -> jnp.ndarray:
     """Bin-to-capacity fallback for the ragged kernel.
 
     Parameters
@@ -165,8 +169,9 @@ def gmm_legacy(xs: jnp.ndarray, w: jnp.ndarray, group_sizes: jnp.ndarray,
         ``round_up(N, 128)`` — exact for any routing, at worst-case cost;
         callers with a tighter guarantee (e.g. a capacity factor) pass it
         to shrink the bins.
-    interpret : bool
-        Run the kernel in interpret mode.
+    interpret : bool, optional
+        Run the kernel in interpret mode; default: decided from the
+        backend (``repro.kernels.resolve_interpret``).
 
     Returns
     -------
@@ -192,5 +197,5 @@ def gmm_legacy(xs: jnp.ndarray, w: jnp.ndarray, group_sizes: jnp.ndarray,
     expert_of_row = jnp.searchsorted(ends, row, side="right")
     slot_of_row = row - offsets[expert_of_row]
     bins = jnp.zeros((E, C, D), xs.dtype).at[expert_of_row, slot_of_row].set(xs)
-    y = gmm_capacity(bins, w, interpret=interpret)
+    y = gmm_capacity(bins, w, interpret=resolve_interpret(interpret))
     return y[expert_of_row, slot_of_row]

@@ -29,15 +29,14 @@ oracles in ref.py are the parity spec (see tests/test_ragged_gmm.py).
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Pallas TPU kernels run in interpret mode everywhere but real TPU.
-INTERPRET = jax.default_backend() != "tpu"
+from repro.kernels import resolve_interpret
 
 
 def _round_up(v: int, m: int) -> int:
@@ -189,7 +188,7 @@ def ragged_gmm(xs: jnp.ndarray,           # (N, D) tokens sorted by expert
                w: jnp.ndarray,            # (E, D, F) expert weights
                group_sizes: jnp.ndarray,  # (E,) rows per expert
                *, bm: int = 128, bn: int = 128,
-               interpret: bool = INTERPRET) -> jnp.ndarray:   # (N, F)
+               interpret: Optional[bool] = None) -> jnp.ndarray:   # (N, F)
     N, D = xs.shape
     E, _, F = w.shape
     bm = _row_tile(N, bm, xs.dtype)
@@ -199,7 +198,8 @@ def ragged_gmm(xs: jnp.ndarray,           # (N, D) tokens sorted by expert
     meta = make_group_metadata(group_sizes, n_pad, bm)
     out = _ragged_call(xs_pad, (w,), meta,
                        functools.partial(_ragged_kernel, bm=bm),
-                       1, F, bm=bm, bn=bn, interpret=interpret)
+                       1, F, bm=bm, bn=bn,
+                       interpret=resolve_interpret(interpret))
     return out[:N]
 
 
@@ -208,7 +208,7 @@ def ragged_gmm(xs: jnp.ndarray,           # (N, D) tokens sorted by expert
 def fused_gate_up(xs: jnp.ndarray, w_gate: jnp.ndarray, w_up: jnp.ndarray,
                   group_sizes: jnp.ndarray, *, activation: str = "silu",
                   bm: int = 128, bn: int = 128,
-                  interpret: bool = INTERPRET) -> jnp.ndarray:
+                  interpret: Optional[bool] = None) -> jnp.ndarray:
     """act(xs @ w_gate[g]) * (xs @ w_up[g]) in ONE launch: (N, D) → (N, F)."""
     N, D = xs.shape
     E, _, F = w_gate.shape
@@ -220,7 +220,7 @@ def fused_gate_up(xs: jnp.ndarray, w_gate: jnp.ndarray, w_up: jnp.ndarray,
     out = _ragged_call(
         xs_pad, (w_gate, w_up), meta,
         functools.partial(_fused_kernel, bm=bm, activation=activation),
-        2, F, bm=bm, bn=bn, interpret=interpret)
+        2, F, bm=bm, bn=bn, interpret=resolve_interpret(interpret))
     return out[:N]
 
 
@@ -231,7 +231,7 @@ def ragged_moe_ffn(xs: jnp.ndarray,       # (N, D) tokens sorted by expert
                    w_down: jnp.ndarray,   # (E, F, D)
                    group_sizes: jnp.ndarray, *, activation: str = "silu",
                    bm: int = 128, bn: int = 128,
-                   interpret: bool = INTERPRET) -> jnp.ndarray:
+                   interpret: Optional[bool] = None) -> jnp.ndarray:
     """Whole expert FFN on expert-sorted tokens in 2 launches (fused gate+up,
     then down).  Group metadata is built once and shared."""
     N, D = xs.shape
@@ -240,6 +240,7 @@ def ragged_moe_ffn(xs: jnp.ndarray,       # (N, D) tokens sorted by expert
     n_pad = _round_up(N, bm)
     xs_pad = jnp.pad(xs, ((0, n_pad - N), (0, 0)))
     meta = make_group_metadata(group_sizes, n_pad, bm)
+    interpret = resolve_interpret(interpret)
     h = _ragged_call(
         xs_pad, (w_gate, w_up), meta,
         functools.partial(_fused_kernel, bm=bm, activation=activation),

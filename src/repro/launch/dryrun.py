@@ -45,12 +45,8 @@ from repro.launch.specs import (SWA_VARIANT_WINDOW, arch_for_shape, input_specs,
 # ---------------------------------------------------------------------------
 
 def hlo_cost_analysis(compiled) -> dict:
-    """Normalize ``compiled.cost_analysis()`` across jax versions: older
-    releases return a dict, newer ones a per-device list of dicts."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
+    """``compiled.cost_analysis()``: one dict of HLO cost totals."""
+    return compiled.cost_analysis()
 
 
 def _collective_bytes(hlo_text: str) -> dict:

@@ -8,22 +8,31 @@ gradient/all-reduce traffic crosses pods; "model" stays intra-pod.
 A FUNCTION, not a module constant: importing this module must never touch
 jax device state (device count is locked at first backend init; see
 launch/dryrun.py which force-creates 512 host devices *before* any import).
+
+Every axis is ``Auto``: the model code places arrays with sharding
+constraints and GSPMD propagation, which ``Explicit`` axes (the
+``jax.make_mesh`` default since jax 0.7) refuse.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")):
     """Small mesh for CPU integration tests (requires >= prod(shape) devices,
     e.g. via XLA_FLAGS=--xla_force_host_platform_device_count=4)."""
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_ep_mesh(ep_degree: int, *, data_degree: int = 1, devices=None):

@@ -38,9 +38,52 @@ from repro.core.autotune import AutoTuner
 from repro.core.proposer import registered_proposers
 from repro.data.pipeline import prompt_batch
 from repro.data.tokenizer import ByteTokenizer
+from repro.distributed.sharding import shard_params
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import Model
 from repro.serving.engine import ServingEngine
 from repro.serving.scheduler import submit_poisson
+
+
+def init_params(model, key, *, mesh=None, mesh_layout: str = "tp"):
+    """``model.init(key)`` placed for serving.  With a mesh the init runs
+    as one program whose outputs land sharded (``shard_params``), so no
+    device ever holds the whole model on the way."""
+    if mesh is None:
+        return model.init(key)
+    shapes = jax.eval_shape(model.init, key)
+    return jax.jit(model.init, out_shardings=shard_params(
+        shapes, mesh, layout=mesh_layout))(key)
+
+
+def build_engine(cfg, *, proposer: str = "model", draft_cfg=None,
+                 moe_dispatch: str = "gmm", paged_attention: str = "kernel",
+                 mesh=None, mesh_layout: str = "tp", seed: int = 0,
+                 **engine_opts) -> ServingEngine:
+    """Target (and draft) with weights drawn from ``seed``, behind one
+    :class:`ServingEngine` — the construction the CLI and ``chip_smoke.py``
+    share.  ``draft_cfg`` is the draft model for the "model"/"prefetch"
+    proposers; "eagle" builds a head on the target, "none" serves AR.
+    ``engine_opts`` go to ``ServingEngine`` unchanged."""
+    layout = mesh_layout if mesh is not None else None
+    target = Model(cfg, moe_dispatch=moe_dispatch,
+                   paged_attention=paged_attention, mesh=mesh,
+                   mesh_layout=layout)
+    params_t = init_params(target, jax.random.PRNGKey(seed), mesh=mesh,
+                           mesh_layout=mesh_layout)
+    if proposer == "eagle":
+        from repro.core.eagle import EagleHead
+        draft = EagleHead(target)
+    elif proposer == "none":
+        draft = None
+    else:
+        draft = Model(draft_cfg)
+    params_d = None if draft is None else init_params(
+        draft, jax.random.PRNGKey(seed + 1), mesh=mesh,
+        mesh_layout=mesh_layout)
+    return ServingEngine(target, draft, params_t, params_d,
+                         proposer=proposer, seed=seed, mesh=mesh,
+                         mesh_layout=layout, **engine_opts)
 
 
 def main():
@@ -144,6 +187,7 @@ def main():
     ap.add_argument("--no-autotune", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, reduced=args.reduced)
     mesh = None
@@ -159,24 +203,14 @@ def main():
         mesh = make_ep_mesh(args.ep_degree)
         print(f"mesh: {dict(mesh.shape)} layout={args.mesh_layout} "
               f"({len(mesh.devices.flat)} devices)")
-    target = Model(cfg, moe_dispatch=args.moe_dispatch,
-                   paged_attention=args.paged_attention, mesh=mesh,
-                   mesh_layout=args.mesh_layout if mesh is not None else None)
-    params_t = target.init(jax.random.PRNGKey(args.seed))
-
-    if args.proposer == "eagle":
-        from repro.core.eagle import EagleHead
-        draft = EagleHead(target)
-        params_d = draft.init(jax.random.PRNGKey(args.seed + 1))
-    elif args.proposer == "none":
-        draft, params_d = None, None
+    if args.proposer in ("none", "eagle"):
+        dcfg = None
+    elif args.reduced:
+        dcfg = draft_for(cfg).with_overrides(
+            num_layers=2, d_model=128, num_heads=4, num_kv_heads=2,
+            d_ff=256, dtype="float32")
     else:
-        dcfg = draft_for(cfg) if not args.reduced else \
-            draft_for(cfg).with_overrides(
-                num_layers=2, d_model=128, num_heads=4, num_kv_heads=2,
-                d_ff=256, dtype="float32")
-        draft = Model(dcfg)
-        params_d = draft.init(jax.random.PRNGKey(args.seed + 1))
+        dcfg = draft_for(cfg)
 
     if args.no_autotune or args.proposer == "none":
         tuner = None
@@ -199,20 +233,20 @@ def main():
         max_rounds_per_request=args.max_rounds_per_request,
         free_page_watermark=args.free_page_watermark,
         max_pool_pages=args.max_pool_pages)
-    eng = ServingEngine(target, draft, params_t, params_d,
-                        max_batch=args.max_batch, tuner=tuner,
-                        gamma=args.gamma, temperature=args.temperature,
-                        proposer=args.proposer, proposer_opts=proposer_opts,
-                        seed=args.seed, timed=args.timed,
-                        scheduler=args.scheduler, eos_id=args.eos_id,
-                        admit_mode=args.admit_mode,
-                        prefill_chunk=args.prefill_chunk,
-                        kv_layout=args.kv_layout, page_size=args.page_size,
-                        prefix_sharing=args.prefix_sharing,
-                        admission_order=args.admission_order,
-                        resilience=resilience, mesh=mesh,
-                        mesh_layout=args.mesh_layout if mesh is not None
-                        else None)
+    eng = build_engine(cfg, proposer=args.proposer, draft_cfg=dcfg,
+                       moe_dispatch=args.moe_dispatch,
+                       paged_attention=args.paged_attention, mesh=mesh,
+                       mesh_layout=args.mesh_layout, seed=args.seed,
+                       max_batch=args.max_batch, tuner=tuner,
+                       gamma=args.gamma, temperature=args.temperature,
+                       proposer_opts=proposer_opts, timed=args.timed,
+                       scheduler=args.scheduler, eos_id=args.eos_id,
+                       admit_mode=args.admit_mode,
+                       prefill_chunk=args.prefill_chunk,
+                       kv_layout=args.kv_layout, page_size=args.page_size,
+                       prefix_sharing=args.prefix_sharing,
+                       admission_order=args.admission_order,
+                       resilience=resilience)
 
     pb = prompt_batch(cfg.vocab_size, args.requests, kind=args.kind,
                       seed=args.seed)

@@ -9,6 +9,8 @@ Three execution modes share one code path:
 
 Caches:
   full attention   {"k": (B, S, Hkv, D), "v": (B, S, Hkv, D)}
+  paged            {"k_pages": (NP, Hkv, page, D), "v_pages": ...} head-major
+                   pool; MLA {"latent_pages": (NP, page, r_kv), ...}
   sliding window   {"k": (B, W, Hkv, D), "v": ..., "pos": (B, W) int32}
                    ring buffer, slot = position % W, ``pos`` init −1
   MLA              {"latent": (B, S, r_kv), "k_rope": (B, S, Dr)}
@@ -91,9 +93,12 @@ def make_attn_cache(cfg, batch: int, max_seq: int, kind: str, dtype, *,
                     paged: bool = False, page_size: int = 64,
                     pool_pages: Optional[int] = None) -> dict:
     """Per-layer decode cache.  ``paged=True`` stores full-attn / MLA
-    sequence axes as a shared physical page pool (``*_pages`` leaves,
-    (pool_pages, page_size, ...)) addressed through the model-level block
-    table; SWA rings are already bounded per row and stay dense."""
+    sequence axes as a shared physical page pool (``*_pages`` leaves)
+    addressed through the model-level block table: K/V pools are
+    head-major (pool_pages, Hkv, page_size, D) so one (page, head) is the
+    contiguous tile the paged kernel reads, MLA pools (pool_pages,
+    page_size, dim).  SWA rings are already bounded per row and stay
+    dense."""
     hd = cfg.head_dim
     if kind == "swa":
         w = min(cfg.sliding_window + SWA_RING_PAD, max_seq)
@@ -113,9 +118,9 @@ def make_attn_cache(cfg, batch: int, max_seq: int, kind: str, dtype, *,
                     (npg, page_size, cfg.mla_qk_rope_dim), dtype),
             }
         return {
-            "k_pages": jnp.zeros((npg, page_size, cfg.num_kv_heads, hd),
+            "k_pages": jnp.zeros((npg, cfg.num_kv_heads, page_size, hd),
                                  dtype),
-            "v_pages": jnp.zeros((npg, page_size, cfg.num_kv_heads, hd),
+            "v_pages": jnp.zeros((npg, cfg.num_kv_heads, page_size, hd),
                                  dtype),
         }
     if kind == "mla":
@@ -131,23 +136,30 @@ def make_attn_cache(cfg, batch: int, max_seq: int, kind: str, dtype, *,
 
 def _paged_write(pool: jnp.ndarray, table: jnp.ndarray,
                  positions: jnp.ndarray, vals: jnp.ndarray) -> jnp.ndarray:
-    """Scatter per-row values at logical ``positions`` (B, T) into the
-    physical page pool (NP, page, ...) through the block table (B, MP).
-    Unallocated positions resolve to the trash page — harmless."""
-    ps = pool.shape[1]
+    """Scatter per-row values ``vals`` (B, T, ...) at logical ``positions``
+    (B, T) into the physical page pool through the block table (B, MP).
+    Every pool keeps its page-slot axis second to last: (NP, page, dim)
+    for MLA, head-major (NP, H, page, D) for K/V.  Unallocated positions
+    resolve to the trash page — harmless."""
+    ps = pool.shape[-2]
     bidx = jnp.arange(positions.shape[0])[:, None]
     pid = table[bidx, positions // ps]                       # (B, T)
-    return pool.at[pid, positions % ps].set(vals)
+    # the (B, T) index dims lead the indexed view whether or not a head
+    # axis sits between them, so it is (B, T, ...) like ``vals``
+    heads = (slice(None),) * (pool.ndim - 3)
+    return pool.at[(pid, *heads, positions % ps)].set(vals)
 
 
 def _paged_view(pool: jnp.ndarray, table: jnp.ndarray):
     """Gather the (B, MP*page, ...) dense view of a paged pool plus its
-    logical key positions.  Stale/trash content is masked the same way
+    logical key positions (a head-major K/V pool yields (B, MP*page, H, D)
+    like a dense cache).  Stale/trash content is masked the same way
     rejected SD suffixes are: the causal mask only admits positions the
     row has actually written (k_pos <= q_pos)."""
     B, MP = table.shape
-    ps = pool.shape[1]
-    view = pool[table].reshape((B, MP * ps) + pool.shape[2:])
+    ps = pool.shape[-2]
+    view = jnp.moveaxis(pool[table], -2, 2)                # (B, MP, ps, ...)
+    view = view.reshape((B, MP * ps) + view.shape[3:])
     k_pos = jnp.broadcast_to(jnp.arange(MP * ps)[None, :], (B, MP * ps))
     return view, k_pos
 
@@ -370,6 +382,7 @@ def gqa_forward(
     causal: bool = True,
     page_table: Optional[jnp.ndarray] = None,
     paged_attention: str = "kernel",
+    mesh=None,
 ) -> Tuple[jnp.ndarray, Optional[dict]]:
     B, T, _ = x.shape
     window = cfg.sliding_window if kind == "swa" else 0
@@ -453,7 +466,7 @@ def gqa_forward(
             from repro.kernels.decode_attention import ops as dec_ops
             out = dec_ops.paged_decode_attention(
                 q, cache["k_pages"], cache["v_pages"], positions[:, 0],
-                page_table, scale=scale, logit_cap=cap)
+                page_table, scale=scale, logit_cap=cap, mesh=mesh)
         else:
             k_view, k_pos = _paged_view(cache["k_pages"], page_table)
             v_view, _ = _paged_view(cache["v_pages"], page_table)

@@ -5,6 +5,7 @@ Everything is functional: ``init_*(key, cfg) -> params`` and
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional, Tuple
 
@@ -14,7 +15,13 @@ import jax.numpy as jnp
 # ---------------------------------------------------------------------------
 # initializers
 # ---------------------------------------------------------------------------
+# Jitted per leaf so the fp32 draw, the scale and the cast fuse into one
+# program that writes only the final dtype: at published widths an eager
+# draw would first materialize every expert tensor in fp32 (4.7 GB for
+# qwen2-57b-a14b's two-layer (2, 64, 3584, 2560) stack).  The draws are
+# bit-identical to the eager ones.
 
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
 def dense_init(key, shape, dtype, scale: float = 1.0):
     """Truncated-normal fan-in init (matches common LLM practice)."""
     fan_in = shape[-2] if len(shape) >= 2 else shape[-1]
@@ -22,6 +29,7 @@ def dense_init(key, shape, dtype, scale: float = 1.0):
     return (jax.random.truncated_normal(key, -3, 3, shape, jnp.float32) * std).astype(dtype)
 
 
+@functools.partial(jax.jit, static_argnums=(1, 2))
 def embed_init(key, shape, dtype):
     return (jax.random.normal(key, shape, jnp.float32) * 0.02).astype(dtype)
 

@@ -24,7 +24,8 @@ place (stale entries are masked by position, see attention.py).
 
 Paged KV (``init_cache(..., paged=True)``): full-attention and MLA slots
 store their sequence axis in fixed-size pages drawn from a shared pool
-(``{"k_pages": (P, NP, page, H, D), ...}``) indexed by a per-row block
+(``{"k_pages": (P, NP, H, page, D), ...}``, head-major so one page of
+one head is a contiguous tile for the paged kernel) indexed by a per-row block
 table ``cache["pages"]["table"]`` (B, max_pages) — physical page 0 is a
 reserved trash page that unallocated/retired rows point at, so stale lanes
 write harmlessly and no index is ever negative.  The table is DATA: page
@@ -144,16 +145,21 @@ def scatter_cache_rows(old: dict, new: dict, rows: jnp.ndarray, *,
         return o.at[:, rows_eff].set(n, mode="drop")
 
     def scatter_paged(o, n):
-        # o: (P, NP, ps, ...), n: (P, R, S_f, ...) — write the prompt pages
-        # of each admitted row through the block table
-        ps = o.shape[2]
+        # o: (P, NP, ..., ps, dim), page slots second to last like every
+        # pool (attention._paged_write); n: (P, R, S_f, ...) — write the
+        # prompt pages of each admitted row through the block table
+        ps = o.shape[-2]
         S_f = n.shape[2]
         span = S_f if n_prompt is None else min(-(-n_prompt // ps) * ps, S_f)
         pos = jnp.arange(span)
         pid = table[rows[:, None], (pos // ps)[None, :]]        # (R, span)
         pid = jnp.where(valid[:, None], pid, o.shape[1])        # drop pads
-        return o.at[:, pid, (pos % ps)[None, :]].set(n[:, :, :span],
-                                                     mode="drop")
+        # layer, page and slot all index arrays: the indexed view is
+        # (P, R, span, ...) like ``n`` with or without a head axis between
+        layer = jnp.arange(o.shape[0])[:, None, None]
+        heads = (slice(None),) * (o.ndim - 4)
+        return o.at[(layer, pid[None], *heads, (pos % ps)[None, None])].set(
+            n[:, :, :span], mode="drop")
 
     paged_to_dense = dict(_PAGED_LEAF_PAIRS)
     layers = []

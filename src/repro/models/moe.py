@@ -89,7 +89,6 @@ def warm_experts(layer_params, cfg, plan: PrefetchPlan, *, mesh=None):
           and mesh.shape["model"] > 1
           and cfg.num_experts % mesh.shape["model"] == 0)
     if ep:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as SP
 
         def _local_gather(w, ids):
@@ -106,11 +105,11 @@ def warm_experts(layer_params, cfg, plan: PrefetchPlan, *, mesh=None):
 
         def gather(w, ids):
             nd = w.ndim
-            return shard_map(
+            return jax.shard_map(
                 _local_gather, mesh=mesh,
                 in_specs=(SP(None, "model", *([None] * (nd - 2))), SP()),
                 out_specs=SP("model", *([None] * nd)),
-                check_rep=False)(w, ids)
+                check_vma=False)(w, ids)
     else:
         gather = jax.vmap(lambda w, ids: jnp.take(w, ids, axis=0))
     warmed = []

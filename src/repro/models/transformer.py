@@ -94,7 +94,7 @@ def block_forward(
             params["mixer"], cfg, h, positions, kind=kind, cache=cache,
             mode=mode, mrope_positions=mrope_positions, use_flash=use_flash,
             causal=causal, page_table=page_table,
-            paged_attention=paged_attention)
+            paged_attention=paged_attention, mesh=mesh)
     elif kind == "mla":
         out, new_cache = attn.mla_forward(
             params["mixer"], cfg, h, positions, cache=cache, mode=mode,

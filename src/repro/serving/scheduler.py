@@ -901,7 +901,7 @@ class ContinuousScheduler:
                 admit_tokens += toks_n
                 landed.extend(batch_in)
             if landed:
-                first = np.asarray(state.last_token)
+                first = jax.device_get(state.last_token)
                 for s, r in landed:
                     s.request, s.active = r, True
                     resume = list(r.resume_tokens or [])

@@ -10,7 +10,7 @@ discipline (fold_in on the loop index), and a donate-and-rebind loop.
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 

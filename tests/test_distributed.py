@@ -1,13 +1,10 @@
 """Distributed layer: sharding rules + a REAL reduced dry-run on a 4-device
 host mesh (subprocess, so the 1-device test environment stays intact)."""
 import json
-import subprocess
-import sys
 import textwrap
 
 import pytest
-
-from repro.configs.registry import get_config
+from _subproc import run_python
 
 # ---------------------------------------------------------------------------
 # pure rule tests (no devices needed)
@@ -97,7 +94,8 @@ _SCRIPT = textwrap.dedent("""
     from repro.serving.serve_step import make_verify_step
 
     arch = sys.argv[1]
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = jax.make_mesh((2, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     cfg = get_config(arch, reduced=True)
     model = Model(cfg, mesh=mesh)
     params = model.init(jax.random.PRNGKey(0))
@@ -141,12 +139,7 @@ _SCRIPT = textwrap.dedent("""
                                   "gemma3-12b", "whisper-base"])
 def test_reduced_mesh_execution(arch):
     """Sharded train step + SD verify step EXECUTE on a 2x2 host mesh."""
-    proc = subprocess.run(
-        [sys.executable, "-c", _SCRIPT, arch],
-        capture_output=True, text=True, timeout=420,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-             "HOME": "/root", "JAX_PLATFORMS": "cpu"},
-        cwd="/root/repo")
+    proc = run_python(["-c", _SCRIPT, arch], timeout=420)
     assert proc.returncode == 0, proc.stderr[-3000:]
     out = json.loads(proc.stdout.strip().splitlines()[-1])
     assert out["ok"]

@@ -2,20 +2,15 @@
 arch x shape) in a subprocess, validating the full launch path + JSON
 contract.  ~60 s; the 80-combo production evidence lives in results/."""
 import json
-import subprocess
-import sys
 
 import pytest
+from _subproc import run_python
 
 
 @pytest.mark.slow
 def test_dryrun_cli_smallest_combo():
-    proc = subprocess.run(
-        [sys.executable, "-m", "repro.launch.dryrun",
-         "--arch", "whisper-base", "--shape", "decode_32k"],
-        capture_output=True, text=True, timeout=600,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root"},
-        cwd="/root/repo")
+    proc = run_python(["-m", "repro.launch.dryrun", "--arch", "whisper-base",
+                       "--shape", "decode_32k"], timeout=600)
     assert proc.returncode == 0, proc.stderr[-2000:]
     rec = json.loads(proc.stdout.strip().splitlines()[-1])
     assert rec["status"] == "ok"

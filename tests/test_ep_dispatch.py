@@ -1,9 +1,9 @@
 """Expert-parallel shard_map dispatch == dense one-hot dispatch (subprocess
 with a forced 4-device mesh)."""
-import subprocess
-import sys
 import textwrap
+
 import pytest
+from _subproc import run_python
 
 pytestmark = pytest.mark.tier1
 
@@ -35,12 +35,7 @@ _SCRIPT = textwrap.dedent("""
 
 
 def test_ep_matches_onehot():
-    proc = subprocess.run(
-        [sys.executable, "-c", _SCRIPT], capture_output=True, text=True,
-        timeout=300,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root",
-             "JAX_PLATFORMS": "cpu"},
-        cwd="/root/repo")
+    proc = run_python(["-c", _SCRIPT], timeout=300)
     assert proc.returncode == 0, proc.stderr[-2000:]
     assert "OK" in proc.stdout
 
@@ -71,11 +66,6 @@ _A2A_SCRIPT = textwrap.dedent("""
 
 def test_a2a_ep_matches_onehot_under_fsdp_layout():
     """Two-hop all-to-all EP (§Perf C5) is numerically exact."""
-    proc = subprocess.run(
-        [sys.executable, "-c", _A2A_SCRIPT], capture_output=True, text=True,
-        timeout=300,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root",
-             "JAX_PLATFORMS": "cpu"},
-        cwd="/root/repo")
+    proc = run_python(["-c", _A2A_SCRIPT], timeout=300)
     assert proc.returncode == 0, proc.stderr[-2000:]
     assert "OK" in proc.stdout

@@ -6,22 +6,16 @@ under sharding, and the zero-retrace guarantee on a warm sharded engine.
 Everything runs in subprocesses because the forced-device XLA flag must be
 set before jax imports; scripts print "OK" markers the tests assert on.
 """
-import subprocess
-import sys
 import textwrap
 
 import pytest
+from _subproc import run_python
 
 pytestmark = pytest.mark.tier1
 
-_ENV = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root",
-        "JAX_PLATFORMS": "cpu"}
-
 
 def _run(script: str, timeout: int = 600):
-    proc = subprocess.run(
-        [sys.executable, "-c", script], capture_output=True, text=True,
-        timeout=timeout, env=_ENV, cwd="/root/repo")
+    proc = run_python(["-c", script], timeout=timeout)
     assert proc.returncode == 0, proc.stderr[-3000:]
     assert "OK" in proc.stdout, proc.stdout[-2000:]
     return proc
@@ -235,3 +229,15 @@ def test_mesh_api_validation_and_deprecation():
     cfg = ModelConfig("m", "dense", 1, 8, 1, 1, 16, 32, dtype="float32")
     with pytest.raises(ValueError, match="model"):
         ServingEngine(Model(cfg), mesh=bad)
+    # the ep dispatch has no single-device fallback: it needs the mesh
+    import jax.numpy as jnp
+    from repro.distributed.collectives import moe_ep_forward
+    from repro.models.moe import init_moe
+    mcfg = ModelConfig("ep", "moe", 1, 16, 2, 2, 32, 64, num_experts=4,
+                       num_experts_per_tok=2, dtype="float32")
+    p = init_moe(jax.random.PRNGKey(0), mcfg, jnp.float32)
+    x = jnp.ones((1, 2, 16), jnp.float32)
+    with pytest.raises(ValueError, match="mesh"):
+        moe_ep_forward(p, mcfg, x)
+    with pytest.raises(ValueError, match="mesh"):
+        moe_ep_forward(p, mcfg, x, mesh=bad)

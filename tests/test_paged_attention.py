@@ -37,8 +37,8 @@ def _paged_case(seed: int, ps: int, T: int):
     shows up as a mismatch against the dense oracle."""
     rng = np.random.default_rng(seed)
     pool_n = B * MP + 1
-    k_pages = rng.normal(size=(pool_n, ps, HKV, D)).astype(np.float32)
-    v_pages = rng.normal(size=(pool_n, ps, HKV, D)).astype(np.float32)
+    k_pages = rng.normal(size=(pool_n, HKV, ps, D)).astype(np.float32)
+    v_pages = rng.normal(size=(pool_n, HKV, ps, D)).astype(np.float32)
     table = rng.permutation(np.arange(1, pool_n)).reshape(B, MP)
     lengths = rng.integers(0, MP * ps - T + 1, size=B).astype(np.int32)
     q = rng.normal(size=(B, T, HQ, D)).astype(np.float32)
@@ -47,8 +47,8 @@ def _paged_case(seed: int, ps: int, T: int):
 
 def _gathered(pool: np.ndarray, table: np.ndarray) -> np.ndarray:
     """The dense (B, MP*ps, Hkv, D) view the gather fallback attends."""
-    g = pool[table]                                   # (B, MP, ps, Hkv, D)
-    return g.reshape(B, -1, *pool.shape[2:])
+    g = pool[table]                                   # (B, MP, Hkv, ps, D)
+    return g.swapaxes(2, 3).reshape(B, -1, HKV, D)
 
 
 def _all_four(q, k_pages, v_pages, lengths, table, cap):
@@ -107,7 +107,7 @@ def test_kernel_reads_forked_tables_and_masks_stale_pages():
             page = table[b, lp]
             lo = max(0, first_dead - lp * ps)
             if lo < ps and page not in table[0, :2]:  # keep shared live
-                pk[page, lo:], pv[page, lo:] = 1e3, -1e3
+                pk[page, :, lo:], pv[page, :, lo:] = 1e3, -1e3
     poisoned = paged_decode_attention(
         jnp.asarray(q), jnp.asarray(pk), jnp.asarray(pv),
         jnp.asarray(lengths), jnp.asarray(table), interpret=True)

@@ -166,3 +166,19 @@ def test_autotuner_prefers_moderate_batch():
     g_small, _ = at.best_gamma(2)
     g_mod, _ = at.best_gamma(win["peak_batch"])
     assert g_mod >= g_small                      # more free verification slack
+
+
+def test_hardware_looked_up_by_device_kind():
+    """The tuner prices the device that serves: a TPU by its device_kind
+    (unknown kinds are an error, not a silent v5e); the CPU keeps the
+    explicit v5e model."""
+    from types import SimpleNamespace
+
+    from repro.core.simulator import hardware_for
+    assert hardware_for() is V5E                       # tests run on the CPU
+    v5e = SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    assert hardware_for(v5e) is V5E
+    with pytest.raises(ValueError, match="TPU v9"):
+        hardware_for(SimpleNamespace(platform="tpu", device_kind="TPU v9"))
+    assert AutoTuner(get_config("qwen2-57b-a14b"),
+                     draft_for(get_config("qwen2-57b-a14b"))).sim.hw is V5E

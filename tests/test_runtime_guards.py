@@ -149,13 +149,8 @@ def test_sharding_guard_detects_second_signature():
     placements (replicated vs split over a real 2-device axis) is a
     violation; an equivalent placement spelled differently is not.
     Needs >1 device, so it runs on forced host devices in a subprocess."""
-    import subprocess
-    import sys
-    env = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root",
-           "JAX_PLATFORMS": "cpu"}
-    res = subprocess.run([sys.executable, "-c", _SECOND_SIG],
-                         capture_output=True, text=True, env=env,
-                         timeout=300)
+    from _subproc import run_python
+    res = run_python(["-c", _SECOND_SIG], timeout=300)
     assert res.returncode == 0, res.stdout + res.stderr
     assert "OK" in res.stdout
 
