@@ -55,7 +55,7 @@ KNOWN_ENTRY_POINTS: Tuple[KnownEntry, ...] = (
                static=("self", "collect")),
     KnownEntry("models/model.py", "Model.extend_with_hidden",
                static=("self", "collect")),
-    KnownEntry("models/model.py", "Model.extend_with_prefetch",
+    KnownEntry("models/model.py", "Model.verify",
                static=("self", "collect")),
     KnownEntry("models/model.py", "Model.commit",
                static=("self", "collected")),

@@ -27,7 +27,7 @@ small-model drafter) and adds the cross-phase coupling:
                  experts' weights executes ahead of verify on the device
                  queue, overlapping the (host-side) verify dispatch instead
                  of serializing with it.
-  4. SCORE     — verify runs through ``Model.extend_with_prefetch``, which
+  4. SCORE     — verify runs through ``Model.verify`` with the plan, which
                  counts hits (activated AND warmed) vs misses per round;
                  the engine aggregates them into ``SDStats`` /
                  ``WaveReport`` / ``session_stats()``.

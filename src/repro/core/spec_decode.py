@@ -208,6 +208,15 @@ class RoundResult:
     (serving/faults.logits_finite): a False row committed NOTHING this
     round (quarantined inside ``finalize``) and should be retired by the
     caller with ``finish_reason="numerical_fault"``.
+
+    ``round_time`` runs from the round's dispatch to the end of its one
+    device fetch; ``dispatch_time`` from the call's entry (key split and
+    ``active`` upload included) until the round's programs are dispatched
+    — host time the device may sit idle through.  ``experts_reached`` is
+    the distinct experts the verify's routing reached in each target MoE
+    layer, summed over the layers (``Model.verify``; the experts whose
+    weights the ragged gmm reads), or None when the target does not count
+    them (dense, or the "ep" dispatch).
     """
     committed: np.ndarray
     n_commit: np.ndarray
@@ -218,6 +227,8 @@ class RoundResult:
     round_time: float
     phase_times: Optional[Dict[str, float]] = None
     finite: Optional[np.ndarray] = None
+    dispatch_time: float = 0.0
+    experts_reached: Optional[int] = None
 
 
 @dataclass
@@ -342,10 +353,12 @@ class SDEngine:
     def _stages(self, gamma: int):
         """(propose, verify, finalize) pure stage functions for one gamma.
 
-        Prefetch-aware proposers (``provides_prefetch``) get a verify stage
-        that additionally takes the round's ``PrefetchPlan`` and returns the
-        hit/miss counts scored by ``Model.extend_with_prefetch``; all other
-        proposers' verify returns ``pf = None``.
+        Verify runs ``Model.verify`` and returns, besides the target
+        distributions, the experts its routing reached (None when the
+        target does not count them).  Prefetch-aware proposers
+        (``provides_prefetch``) pass the round's ``PrefetchPlan`` too and
+        get its hit/miss counts back as ``pf``; all other proposers' verify
+        returns ``pf = None``.
         """
         target, proposer, temp = self.target, self.proposer, self.temperature
         pf_aware = getattr(proposer, "provides_prefetch", False)
@@ -356,29 +369,17 @@ class SDEngine:
         # the numerical sentinel reads RAW verify logits: the greedy
         # probs_from_logits branch is one_hot(argmax), and argmax of an
         # all-NaN row returns a valid index — probabilities hide faults
-        if pf_aware:
-            def verify(params_t, t_cache, last_token, drafts, plan):
-                verify_tokens = jnp.concatenate([last_token[:, None], drafts],
-                                                1)
-                logits, hidden, pend, pf = target.extend_with_prefetch(
-                    params_t, verify_tokens, t_cache, plan, collect=True)
-                if not proposer.needs_hidden:
-                    hidden = None
-                return (probs_from_logits(logits, temp), hidden, pend, pf,
-                        logits_finite(logits))
-        else:
-            def verify(params_t, t_cache, last_token, drafts):
-                verify_tokens = jnp.concatenate([last_token[:, None], drafts],
-                                                1)
-                if proposer.needs_hidden:
-                    logits, hidden, pend = target.extend_with_hidden(
-                        params_t, verify_tokens, t_cache, collect=True)
-                else:
-                    logits, pend = target.extend(params_t, verify_tokens,
-                                                 t_cache, collect=True)
-                    hidden = None
-                return (probs_from_logits(logits, temp), hidden, pend, None,
-                        logits_finite(logits))
+        def verify(params_t, t_cache, last_token, drafts, plan=None):
+            verify_tokens = jnp.concatenate([last_token[:, None], drafts], 1)
+            logits, hidden, pend, counts = target.verify(
+                params_t, verify_tokens, t_cache, plan=plan, collect=True)
+            if not proposer.needs_hidden:
+                hidden = None
+            pf = None
+            if pf_aware:
+                pf = {k: counts[k] for k in ("hits", "actual", "predicted")}
+            return (probs_from_logits(logits, temp), hidden, pend, pf,
+                    logits_finite(logits), counts.get("experts_reached"))
 
         def finalize(params, pend, p_state, base_len, p_dist, q_dist, drafts,
                      hidden, last_token, active, finite, k_rej):
@@ -432,13 +433,13 @@ class SDEngine:
                 base_len = t_cache["lengths"]
                 drafts, q_dist, p_work = propose(params, p_state, last_token,
                                                  k_prop)
-                p_dist, hidden, pend, pf, finite = verify(
+                p_dist, hidden, pend, pf, finite, reached = verify(
                     params["target"], t_cache, last_token, drafts)
                 out = finalize(params, pend, p_work, base_len, p_dist,
                                q_dist, drafts, hidden, last_token, active,
                                finite, k_rej)
                 out = (self._constrain_cache(out[0]),) + out[1:]
-                return out + (finite, pf)
+                return out + (finite, pf, reached)
 
             fn = jax.jit(round_fn)
             self._round_cache[gamma] = fn
@@ -596,77 +597,61 @@ class SDEngine:
         (SessionState, RoundResult)
             The advanced state and the round's host-side outcome.
         """
-        gamma = self.gamma if gamma is None else gamma
-        if key is None:
-            # greedy rounds are key-independent; at temperature>0 a fixed
-            # default would silently reuse IDENTICAL propose/reject noise
-            # every round of the caller's loop — fail loudly instead
-            if self.temperature > 0.0:
-                raise ValueError(
-                    "round() needs a fresh per-round key at temperature>0 "
-                    "(split one from a root key each round)")
-            # built once: a fresh PRNGKey here would be one implicit
-            # host-to-device transfer per round (transfer_guard counts it)
-            if self._greedy_key is None:
-                self._greedy_key = jax.random.PRNGKey(0)
-            key = self._greedy_key
-        k_prop, k_rej = jax.random.split(key)
-        B = state.batch
-        active = self._host(np.ones((B,), bool) if active is None
-                            else active, bool)
+        t_entry = time.perf_counter()
+        with jax.profiler.TraceAnnotation("sd.round"):
+            return self._round(state, gamma, key, active, timed, t_entry)
+
+    def _round(self, state: SessionState, gamma: Optional[int], key,
+               active, timed: bool, t_entry: float
+               ) -> Tuple[SessionState, RoundResult]:
+        """``round``'s body, in three profiler spans: ``sd.round.prepare``
+        (keys, ``active`` upload), ``sd.round.dispatch`` (the round's
+        programs dispatched) and ``sd.round.fetch`` (the one device_get)."""
+        with jax.profiler.TraceAnnotation("sd.round.prepare"):
+            gamma = self.gamma if gamma is None else gamma
+            if key is None:
+                # greedy rounds are key-independent; at temperature>0 a
+                # fixed default would silently reuse IDENTICAL propose/
+                # reject noise every round of the caller's loop — fail
+                # loudly instead
+                if self.temperature > 0.0:
+                    raise ValueError(
+                        "round() needs a fresh per-round key at "
+                        "temperature>0 (split one from a root key each "
+                        "round)")
+                # built once: a fresh PRNGKey here would be one implicit
+                # host-to-device transfer per round (transfer_guard counts
+                # it)
+                if self._greedy_key is None:
+                    self._greedy_key = jax.random.PRNGKey(0)
+                key = self._greedy_key
+            k_prop, k_rej = jax.random.split(key)
+            B = state.batch
+            active = self._host(np.ones((B,), bool) if active is None
+                                else active, bool)
         params = state.params
         pf_aware = getattr(self.proposer, "provides_prefetch", False)
         staged = timed or pf_aware
         phases: Dict[str, float] = {}
         t_round = time.perf_counter()
-        if staged:
-            j_prop, j_verify, j_fin, j_warm = self._staged_jits(gamma)
-            t_cache, p_state, last_token = (state.t_cache, state.p_state,
-                                            state.last_token)
-            base_len = t_cache["lengths"]
-            t0 = time.perf_counter()
-            drafts, q_dist, p_work = j_prop(params, p_state, last_token,
-                                            k_prop)
-            if timed:
-                jax.block_until_ready(drafts)
-                phases["propose"] = time.perf_counter() - t0
-            if j_warm is not None:
-                # async dispatch, never blocked on: the gather of the
-                # predicted experts' weights runs ahead of verify on the
-                # device queue while the host assembles the verify call
-                t0 = time.perf_counter()
-                j_warm(params["target"], p_work["plan"])
-                if timed:
-                    # timed-only, like the other phase stats (and like
-                    # them the first round includes trace+compile)
-                    phases["warm"] = time.perf_counter() - t0
-            t0 = time.perf_counter()
-            if pf_aware:
-                p_dist, hidden, pend, pf, finite = j_verify(
-                    params["target"], t_cache, last_token, drafts,
-                    p_work["plan"])
+        with jax.profiler.TraceAnnotation("sd.round.dispatch"):
+            if staged:
+                (t_cache, p_state, last_token, committed, n_commit, n_acc,
+                 finite, pf, reached) = self._staged_round(
+                    state, gamma, active, k_prop, k_rej, timed, phases)
             else:
-                p_dist, hidden, pend, pf, finite = j_verify(
-                    params["target"], t_cache, last_token, drafts)
-            if timed:
-                jax.block_until_ready(p_dist)
-                phases["verify"] = time.perf_counter() - t0
-            t0 = time.perf_counter()
-            (t_cache, p_state, last_token, committed, n_commit, n_acc) = \
-                j_fin(params, pend, p_work, base_len, p_dist, q_dist,
-                      drafts, hidden, last_token, active, finite, k_rej)
-            if timed:
-                jax.block_until_ready(committed)
-                phases["reject"] = time.perf_counter() - t0
-        else:
-            fn = self._round_fn(gamma)
-            (t_cache, p_state, last_token, committed, n_commit, n_acc,
-             finite, pf) = fn(params, state.t_cache, state.p_state,
-                              state.last_token, active, k_prop, k_rej)
+                fn = self._round_fn(gamma)
+                (t_cache, p_state, last_token, committed, n_commit, n_acc,
+                 finite, pf, reached) = fn(params, state.t_cache,
+                                           state.p_state, state.last_token,
+                                           active, k_prop, k_rej)
+        dispatch_time = time.perf_counter() - t_entry
         # the round's one device sync: an explicit fetch of everything the
-        # host reads (np.asarray on each would be four implicit transfers)
-        committed, n_commit_np, n_acc, finite, pf = jax.device_get(
-            (committed, n_commit, n_acc, finite, pf))
+        # host reads (np.asarray on each would be five implicit transfers)
+        with jax.profiler.TraceAnnotation("sd.round.fetch"):
+            committed, n_commit_np, n_acc, finite, pf, reached = \
+                jax.device_get((committed, n_commit, n_acc, finite, pf,
+                                reached))
         round_time = time.perf_counter() - t_round
         pf_counts = None
         if pf is not None:
@@ -678,8 +663,50 @@ class SDEngine:
             n_accept=n_acc, width=committed.shape[1] - 1,
             gamma=gamma, pf=pf_counts, round_time=round_time,
             phase_times=phases if timed else None,
-            finite=finite)
+            finite=finite, dispatch_time=dispatch_time,
+            experts_reached=None if reached is None else int(reached))
         return new_state, result
+
+    def _staged_round(self, state: SessionState, gamma: int, active, k_prop,
+                      k_rej, timed: bool, phases: Dict[str, float]):
+        """One round through the separately-jitted stages (``timed`` rounds
+        block after each stage and record its time in ``phases``); returns
+        the fused round's outputs."""
+        j_prop, j_verify, j_fin, j_warm = self._staged_jits(gamma)
+        params = state.params
+        t_cache, p_state, last_token = (state.t_cache, state.p_state,
+                                        state.last_token)
+        base_len = t_cache["lengths"]
+        pf_aware = getattr(self.proposer, "provides_prefetch", False)
+        t0 = time.perf_counter()
+        drafts, q_dist, p_work = j_prop(params, p_state, last_token, k_prop)
+        if timed:
+            jax.block_until_ready(drafts)
+            phases["propose"] = time.perf_counter() - t0
+        if j_warm is not None:
+            # async dispatch, never blocked on: the gather of the predicted
+            # experts' weights runs ahead of verify on the device queue
+            # while the host assembles the verify call
+            t0 = time.perf_counter()
+            j_warm(params["target"], p_work["plan"])
+            if timed:
+                # timed-only, like the other phase stats (and like them
+                # the first round includes trace+compile)
+                phases["warm"] = time.perf_counter() - t0
+        t0 = time.perf_counter()
+        plan = p_work["plan"] if pf_aware else None
+        p_dist, hidden, pend, pf, finite, reached = j_verify(
+            params["target"], t_cache, last_token, drafts, plan)
+        if timed:
+            jax.block_until_ready(p_dist)
+            phases["verify"] = time.perf_counter() - t0
+        t0 = time.perf_counter()
+        out = j_fin(params, pend, p_work, base_len, p_dist, q_dist, drafts,
+                    hidden, last_token, active, finite, k_rej)
+        if timed:
+            jax.block_until_ready(out[3])
+            phases["reject"] = time.perf_counter() - t0
+        return out + (finite, pf, reached)
 
     # -------------------------------------------------------------- admission
     def _admit_fn(self, B: int, Tp: int, max_seq: int) -> Callable:

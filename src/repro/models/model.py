@@ -821,21 +821,24 @@ class Model:
                                                collect=collect)
         return logits, pend
 
-    def extend_with_prefetch(self, params, tokens, cache, plan, *,
-                             collect: bool = False):
-        """Verify forward that scores an expert-prefetch plan as it runs.
+    @property
+    def counts_experts(self) -> bool:
+        """True when :meth:`verify` reports ``experts_reached``: the model
+        has routed-MoE layers and dispatches them on this device ("gmm" /
+        "onehot"; the "ep" dispatch routes inside its shards)."""
+        return any(self.cfg.moe_pattern) and self.moe_dispatch != "ep"
 
-        Identical compute to :meth:`extend` (same logits, same cache
-        discipline), but each MoE layer additionally compares the experts it
-        actually routed to against ``plan.masks`` — the prediction whose
-        weights were warmed during the propose phase.
+    def verify(self, params, tokens, cache, *, plan=None,
+               collect: bool = False):
+        """The SD verify forward: :meth:`extend` plus the routing counts.
 
         Parameters
         ----------
         params, tokens, cache
             As :meth:`extend`; ``tokens`` is the (B, gamma+1) verify stream.
-        plan : models.moe.PrefetchPlan
-            The warm plan built from the draft token stream.
+        plan : models.moe.PrefetchPlan, optional
+            The warm plan built from the draft token stream; when given,
+            each MoE layer also scores it against the experts it routed to.
         collect : bool
             As :meth:`extend` (recurrent per-step state collection).
 
@@ -845,20 +848,27 @@ class Model:
             (B, T, V) next-token logits.
         hidden : jnp.ndarray
             (B, T, d) final pre-head hidden states (for hidden-feeding
-            proposers; ignored otherwise).
+            proposers; dead code otherwise).
         pend : dict
             Pending cache for :meth:`commit`.
-        pf : dict
-            int32 scalars ``{"hits", "actual", "predicted"}`` summed over
-            all MoE layers and periods — the verify pass's prefetch
-            hit/miss accounting.
+        counts : dict
+            int32 scalars summed over all MoE layers and periods:
+            ``experts_reached`` (the distinct experts each layer's routing
+            of all B*T rows reached; only when :attr:`counts_experts`) and,
+            with a ``plan``, the prefetch ``hits``/``actual``/``predicted``
+            (``actual`` is ``experts_reached`` on the "gmm"/"onehot"
+            dispatches).
         """
         logits, x, pend, metrics = self._extend_impl(
             params, tokens, cache, collect=collect,
-            prefetch_masks=list(plan.masks))
-        pf = {k: metrics[f"prefetch_{k}"]
-              for k in ("hits", "actual", "predicted")}
-        return logits, x, pend, pf
+            prefetch_masks=None if plan is None else list(plan.masks))
+        counts = {}
+        if self.counts_experts:
+            counts["experts_reached"] = metrics["experts_reached"]
+        if plan is not None:
+            counts.update({k: metrics[f"prefetch_{k}"]
+                           for k in ("hits", "actual", "predicted")})
+        return logits, x, pend, counts
 
     def extend_with_hidden(self, params, tokens, cache, *, collect=False):
         """extend() variant that also returns the final hidden states

@@ -123,18 +123,22 @@ def warm_experts(layer_params, cfg, plan: PrefetchPlan, *, mesh=None):
     return warmed
 
 
-def prefetch_hit_stats(prefetch_mask: jnp.ndarray, indices: jnp.ndarray,
-                       num_experts: int) -> dict:
+def reached_experts(indices: jnp.ndarray, num_experts: int) -> jnp.ndarray:
+    """``(E,)`` bool: the experts that ``(N, K)`` routed ids reach."""
+    return jnp.zeros((num_experts,), bool).at[indices.reshape(-1)].set(True)
+
+
+def prefetch_hit_stats(prefetch_mask: jnp.ndarray,
+                       reached: jnp.ndarray) -> dict:
     """Score one layer's prediction against the experts actually routed to.
 
     Parameters
     ----------
     prefetch_mask : jnp.ndarray
         ``(E,)`` bool — experts the plan predicted (and warmed).
-    indices : jnp.ndarray
-        ``(N, K)`` routed expert ids from this forward.
-    num_experts : int
-        E.
+    reached : jnp.ndarray
+        ``(E,)`` bool — experts this forward's routing reached, the mask
+        behind ``moe_forward``'s ``experts_reached`` count.
 
     Returns
     -------
@@ -143,11 +147,10 @@ def prefetch_hit_stats(prefetch_mask: jnp.ndarray, indices: jnp.ndarray,
         (activated), ``predicted`` (warmed) — the per-wave hit/miss
         accounting aggregated by the engine.
     """
-    actual = jnp.zeros((num_experts,), bool).at[indices.reshape(-1)].set(True)
     predicted = prefetch_mask.astype(bool)
     return {
-        "prefetch_hits": jnp.sum(actual & predicted).astype(jnp.int32),
-        "prefetch_actual": jnp.sum(actual).astype(jnp.int32),
+        "prefetch_hits": jnp.sum(reached & predicted).astype(jnp.int32),
+        "prefetch_actual": jnp.sum(reached).astype(jnp.int32),
         "prefetch_predicted": jnp.sum(predicted).astype(jnp.int32),
     }
 
@@ -219,19 +222,19 @@ def _dispatch_onehot(params, cfg, x, weights, indices):
     return jnp.einsum("end,ne->nd", y, combine.astype(y.dtype))
 
 
-def _dispatch_gmm(params, cfg, x, weights, indices):
+def _dispatch_gmm(params, cfg, x, weights, indices, group_sizes):
     """Sort tokens by expert, ragged grouped matmul (kernels/gmm/ragged.py):
     fused gate+up launch then down launch — 2 Pallas calls per MoE FFN, and
-    expert GEMM work scales with the routed token count N*K, not E*C."""
+    expert GEMM work scales with the routed token count N*K, not E*C.
+    ``group_sizes`` (E,) is the routed-row count per expert."""
     from repro.kernels.gmm import ops as gmm_ops
 
     N, d = x.shape
-    K, E = cfg.num_experts_per_tok, cfg.num_experts
+    K = cfg.num_experts_per_tok
     flat_expert = indices.reshape(-1)                       # (N*K,)
     order = jnp.argsort(flat_expert)
     token_of = order // K                                   # source token per slot
     xs = x[token_of]                                        # (N*K, d) sorted by expert
-    group_sizes = jnp.bincount(flat_expert, length=E)
 
     ys = gmm_ops.ragged_moe_ffn(
         xs, params["w_gate"], params["w_up"], params["w_down"], group_sizes,
@@ -276,7 +279,8 @@ def moe_forward(
     prefetch_mask : jnp.ndarray, optional
         (E,) predicted-hot expert mask from a PrefetchPlan; when given, the
         returned metrics include prefetch hit/miss counts scored against
-        this forward's actual routing.
+        this forward's actual routing (``prefetch_actual`` is
+        ``experts_reached``).
     mesh, mesh_layout : optional
         Device mesh (and layout) threaded explicitly to the "ep" dispatch
         and ignored by the single-device dispatches — see
@@ -285,8 +289,14 @@ def moe_forward(
     Returns
     -------
     (jnp.ndarray, dict or None)
-        (B, T, d) output and the requested metrics (None if neither
-        ``return_metrics`` nor ``prefetch_mask``).
+        (B, T, d) output and the metrics.  The "onehot" and "gmm"
+        dispatches always report ``experts_reached``: the int32 count of
+        distinct experts the routing of all B*T rows reaches (padding
+        rows included — the expert FFN runs them too), the experts whose
+        weights the ragged gmm reads.  "ep" reports it only with a
+        ``prefetch_mask`` (as ``prefetch_actual``, from a re-route), and
+        returns None when neither ``return_metrics`` nor
+        ``prefetch_mask`` is given.
     """
     B, T, d = x.shape
     if dispatch == "ep":
@@ -307,31 +317,33 @@ def moe_forward(
                         indices, cfg.num_experts),
                 }
             if prefetch_mask is not None:
-                metrics = dict(metrics or {},
-                               **prefetch_hit_stats(prefetch_mask, indices,
-                                                    cfg.num_experts))
+                metrics = dict(metrics or {}, **prefetch_hit_stats(
+                    prefetch_mask, reached_experts(indices, cfg.num_experts)))
         return y, metrics
     xf = x.reshape(B * T, d)
     weights, indices, probs = router_topk(params, cfg, xf, rng)
     if dispatch == "gmm":
-        y = _dispatch_gmm(params, cfg, xf, weights.astype(x.dtype), indices)
+        group_sizes = jnp.bincount(indices.reshape(-1),
+                                   length=cfg.num_experts)
+        y = _dispatch_gmm(params, cfg, xf, weights.astype(x.dtype), indices,
+                          group_sizes)
+        reached = group_sizes > 0
     else:
         y = _dispatch_onehot(params, cfg, xf, weights.astype(x.dtype), indices)
+        reached = reached_experts(indices, cfg.num_experts)
     if "shared" in params:
         s = params["shared"]
         h = _act(xf @ s["w_gate"], cfg.mlp_activation) * (xf @ s["w_up"])
         y = y + h @ s["w_down"]
     y = y.reshape(B, T, d)
-    metrics = None
+    metrics = {"experts_reached": jnp.sum(reached).astype(jnp.int32)}
     if return_metrics:
-        metrics = {
-            "aux_loss": load_balance_loss(probs, indices, cfg.num_experts),
-            "expert_counts": expert_activation_counts(indices, cfg.num_experts),
-        }
+        metrics["aux_loss"] = load_balance_loss(probs, indices,
+                                                cfg.num_experts)
+        metrics["expert_counts"] = expert_activation_counts(indices,
+                                                            cfg.num_experts)
     if prefetch_mask is not None:
-        # score the warm plan against the experts this forward actually hit;
-        # cheap (one (E,) scatter) and decode-only — train never passes a mask
-        metrics = dict(metrics or {},
-                       **prefetch_hit_stats(prefetch_mask, indices,
-                                            cfg.num_experts))
+        # score the warm plan against the experts this forward actually
+        # reached — decode-only, train never passes a mask
+        metrics.update(prefetch_hit_stats(prefetch_mask, reached))
     return y, metrics

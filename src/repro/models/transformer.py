@@ -118,7 +118,8 @@ def block_forward(
                "expert_counts": jnp.zeros((max(cfg.num_experts, 1),), jnp.int32),
                "prefetch_hits": jnp.zeros((), jnp.int32),
                "prefetch_actual": jnp.zeros((), jnp.int32),
-               "prefetch_predicted": jnp.zeros((), jnp.int32)}
+               "prefetch_predicted": jnp.zeros((), jnp.int32),
+               "experts_reached": jnp.zeros((), jnp.int32)}
     if "ffn" in params:
         h = apply_norm(params["norm2"], x, cfg.norm_eps)
         if is_moe:
@@ -129,13 +130,7 @@ def block_forward(
                                        return_metrics=want_metrics,
                                        prefetch_mask=prefetch_mask,
                                        mesh=mesh, mesh_layout=mesh_layout)
-            if want_metrics:
-                metrics["aux_loss"] = m["aux_loss"]
-                metrics["expert_counts"] = m["expert_counts"]
-            if prefetch_mask is not None:
-                for k in ("prefetch_hits", "prefetch_actual",
-                          "prefetch_predicted"):
-                    metrics[k] = m[k]
+            metrics.update(m or {})
         else:
             y = apply_mlp(params["ffn"], h, cfg.mlp_activation)
         x = x + y
@@ -202,7 +197,9 @@ def stack_forward(
     ``prefetch_masks`` (optional) is a per-period-slot list of ``(P, E)``
     predicted-hot expert masks (models/moe.PrefetchPlan.masks); when given,
     the returned metrics include ``prefetch_hits/actual/predicted`` counts
-    summed over all MoE layers.
+    summed over all MoE layers.  ``experts_reached`` is always the
+    distinct experts each MoE layer's routing reached, summed over the
+    layers (zero for the "ep" dispatch, which does not count them).
 
     ``page_table`` (optional) is the (B, max_pages) logical→physical block
     table of a paged cache (models/model.py) — shared by every paged

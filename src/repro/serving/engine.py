@@ -69,7 +69,14 @@ class Request:
     max_new_tokens: int = 64
     temperature: float = 0.0
     output: Optional[np.ndarray] = None
+    # host clock (time.perf_counter) at the request's lifecycle points:
+    # submitted, taken from the queue into a slot (continuous) or a wave,
+    # its first token on the host (continuous: after the admission
+    # prefill's fetch), finished.  A preempted and re-admitted request
+    # keeps its first admitted_at / first_token_at.
     submitted_at: float = field(default_factory=time.perf_counter)
+    admitted_at: Optional[float] = None
+    first_token_at: Optional[float] = None
     finished_at: Optional[float] = None
     sampling: Optional[SamplingParams] = None
     # "length" | "eos" | "rejected" | "numerical_fault" | "timeout" |
@@ -387,6 +394,9 @@ class ServingEngine:
         wave = []
         while self.queue and len(wave) < self.max_batch:
             wave.append(self.queue.popleft())
+        t = time.perf_counter()
+        for r in wave:
+            r.admitted_at = t
         return wave
 
     @property

@@ -57,6 +57,7 @@ tokens per slot.
 from __future__ import annotations
 
 import time
+from contextlib import contextmanager
 from dataclasses import dataclass, field, replace as dc_replace
 from typing import TYPE_CHECKING, List, Optional, Tuple
 
@@ -162,6 +163,17 @@ class StepReport:
     ``shared_tokens`` counts prompt tokens this boundary's admissions did
     NOT prefill because prefix sharing mapped them to a sibling's pages
     (docs/paged_attention.md) — the per-round admission work saved.
+
+    Host time (``time.perf_counter`` seconds; docs/metrics.md, "Tracing a
+    live engine"): ``round_time`` is ``RoundResult.round_time``, dispatch
+    to the round's one fetch; ``dispatch_time`` is
+    ``RoundResult.dispatch_time``, the round call's entry until its
+    programs are dispatched; ``admit_time`` is this boundary's admission
+    dispatches plus the first-token fetch that waits on their prefill
+    (the ``serve.admit`` and ``serve.admit_fetch`` spans), 0 when nothing
+    was admitted.  ``experts_reached`` is ``RoundResult.experts_reached``:
+    the distinct experts the verify reached per target MoE layer, summed
+    over the layers (None when the target does not count them).
     """
     round_index: int
     live: int
@@ -178,6 +190,9 @@ class StepReport:
     timeouts: int = 0
     deferred: int = 0
     shared_tokens: int = 0
+    dispatch_time: float = 0.0
+    admit_time: float = 0.0
+    experts_reached: Optional[int] = None
 
 
 @dataclass
@@ -210,6 +225,7 @@ class ContinuousScheduler:
         self._consec_faulty = 0
         self._consec_stall = 0
         self._forced_ar = False
+        self._admit_s = 0.0                  # this boundary's admit_time
 
     # ------------------------------------------------------------- admission
     def _pop_admissible(self, round_idx: int) -> Optional["Request"]:
@@ -368,15 +384,16 @@ class ContinuousScheduler:
         device_put with the SAME cache_spec placement the session opened
         with, so sharded rounds never see a placement flip."""
         eng = self.engine
-        table = np.asarray(self._alloc.table, np.int32)
-        if eng.mesh is not None:
-            from jax.sharding import NamedSharding
-            from repro.distributed.sharding import cache_spec
-            new = jax.device_put(table, NamedSharding(
-                eng.mesh, cache_spec("pages/table", table.shape,
-                                     mesh=eng.mesh)))
-        else:
-            new = jnp.asarray(table)
+        with jax.profiler.TraceAnnotation("serve.table_sync"):
+            table = np.asarray(self._alloc.table, np.int32)
+            if eng.mesh is not None:
+                from jax.sharding import NamedSharding
+                from repro.distributed.sharding import cache_spec
+                new = jax.device_put(table, NamedSharding(
+                    eng.mesh, cache_spec("pages/table", table.shape,
+                                         mesh=eng.mesh)))
+            else:
+                new = jnp.asarray(table)
         pages = dict(state.t_cache["pages"], table=new)
         return dc_replace(state, t_cache=dict(state.t_cache, pages=pages))
 
@@ -389,14 +406,15 @@ class ContinuousScheduler:
         from repro.models.model import grow_cache_seq
         alloc = self._alloc
         new_cap = max_pages * alloc.page_size
-        state = sess.grow_session(state, new_cap, pool_pages=pool_pages,
-                                  max_pages=max_pages)
-        alloc.grow(pool_pages, max_pages)
-        state = self._sync_table(state)
-        for c in chunking:
-            if c.pa.t_cache is not None:
-                c.pa = dc_replace(c.pa, t_cache=grow_cache_seq(
-                    c.pa.t_cache, self.engine.target.cfg, new_cap))
+        with jax.profiler.TraceAnnotation("serve.grow"):
+            state = sess.grow_session(state, new_cap, pool_pages=pool_pages,
+                                      max_pages=max_pages)
+            alloc.grow(pool_pages, max_pages)
+            state = self._sync_table(state)
+            for c in chunking:
+                if c.pa.t_cache is not None:
+                    c.pa = dc_replace(c.pa, t_cache=grow_cache_seq(
+                        c.pa.t_cache, self.engine.target.cfg, new_cap))
         return state
 
     def _headroom_ok(self, need_pages: int, live: int) -> bool:
@@ -526,6 +544,18 @@ class ContinuousScheduler:
         the cache was sized at stream start and cannot hold it; paged:
         it cannot fit even a drained pool at ``max_pool_pages``)."""
         self._finish_request(r, "rejected")
+
+    @contextmanager
+    def _admission(self, span: str):
+        """A profiler span around admission work (``serve.admit`` /
+        ``serve.admit_fetch``) whose host seconds add to this boundary's
+        ``StepReport.admit_time``."""
+        t0 = time.perf_counter()
+        try:
+            with jax.profiler.TraceAnnotation(span):
+                yield
+        finally:
+            self._admit_s += time.perf_counter() - t0
 
     def _admit_batch(self, sess, state: SessionState,
                      batch_in: List[Tuple[SlotState, "Request"]]
@@ -681,7 +711,17 @@ class ContinuousScheduler:
         (4) decode one SD round with the active mask; (5) credit tokens
         per slot, applying per-slot budgets and eos, freeing pages of
         retired rows.  Returns ``None`` on an empty queue.
+
+        Profiler spans (docs/metrics.md, "Tracing a live engine"):
+        ``serve.stream`` holds the whole call; each round has a
+        ``serve.boundary`` (steps 1-3, up to the round call) and a
+        ``serve.credit`` (step 5), and the boundary holds ``serve.admit``,
+        ``serve.admit_fetch``, ``serve.table_sync`` and ``serve.grow``.
         """
+        with jax.profiler.TraceAnnotation("serve.stream"):
+            return self._run_stream()
+
+    def _run_stream(self) -> Optional[WaveReport]:
         eng = self.engine
         if not eng.queue:
             return None
@@ -742,333 +782,350 @@ class ContinuousScheduler:
         round_idx = 0
         t_start = time.perf_counter()
         while True:
-            admit_credited, landed, n_retired = 0, [], 0
-            admit_rows_n, admit_tokens, deferred_n = 0, 0, 0
-            faults_n, timeouts_n, shared_tok_n = 0, 0, 0
-            cow_pairs: List[Tuple[int, int]] = []
-            prefix_in: List[Tuple[SlotState, "Request", int]] = []
-            self._round_preempted = 0
-            self._table_dirty = False
-            had_admissible = self._has_admissible(round_idx)
-            if inj is not None and self._alloc is not None:
-                # scripted page holds: release expired, apply due ones
-                inj.page_service(round_idx, self._alloc)
-            # ---- advance chunked admissions: one chunk per round boundary
-            for c in list(chunking):
-                R, C = c.pa.prompts.shape[0], c.pa.chunk
-                state, pa = sess.admit_chunk(state, c.pa)
-                admit_rows_n += R
-                admit_tokens += R * min(C, c.pa.remaining)
-                if pa is None:
-                    chunking.remove(c)
-                    landed.append((c.slot, c.request))
+            with jax.profiler.TraceAnnotation("serve.boundary"):
+                admit_credited, landed, n_retired = 0, [], 0
+                admit_rows_n, admit_tokens, deferred_n = 0, 0, 0
+                faults_n, timeouts_n, shared_tok_n = 0, 0, 0
+                cow_pairs: List[Tuple[int, int]] = []
+                prefix_in: List[Tuple[SlotState, "Request", int]] = []
+                self._round_preempted = 0
+                self._table_dirty = False
+                self._admit_s = 0.0
+                had_admissible = self._has_admissible(round_idx)
+                if inj is not None and self._alloc is not None:
+                    # scripted page holds: release expired, apply due ones
+                    inj.page_service(round_idx, self._alloc)
+                # ---- advance chunked admissions: one chunk per round boundary
+                for c in list(chunking):
+                    R, C = c.pa.prompts.shape[0], c.pa.chunk
+                    with self._admission("serve.admit"):
+                        state, pa = sess.admit_chunk(state, c.pa)
+                    admit_rows_n += R
+                    admit_tokens += R * min(C, c.pa.remaining)
+                    if pa is None:
+                        chunking.remove(c)
+                        landed.append((c.slot, c.request))
+                    else:
+                        c.pa = pa
+                # ---- admit: one sliced prefill covers every refill this round
+                # (slots whose chunked admission just landed activate below —
+                # reserve them so the refill loop can't double-admit the row;
+                # a preemption inside _make_room frees its victim's slot, so
+                # the free set is recomputed every iteration)
+                claimed = {c.slot.index for c in chunking} \
+                    | {s.index for s, _ in landed}
+                batch_in: List[Tuple[SlotState, "Request"]] = []
+                live_now = sum(1 for s in slots if s.active)
+                if inj is not None and inj.admission_fails(round_idx):
+                    # scripted transient admission failure: bounded
+                    # retry-with-backoff for everything admissible this round
+                    deferred_n += self._defer_admissible(round_idx)
                 else:
-                    c.pa = pa
-            # ---- admit: one sliced prefill covers every refill this round
-            # (slots whose chunked admission just landed activate below —
-            # reserve them so the refill loop can't double-admit the row;
-            # a preemption inside _make_room frees its victim's slot, so
-            # the free set is recomputed every iteration)
-            claimed = {c.slot.index for c in chunking} \
-                | {s.index for s, _ in landed}
-            batch_in: List[Tuple[SlotState, "Request"]] = []
-            live_now = sum(1 for s in slots if s.active)
-            if inj is not None and inj.admission_fails(round_idx):
-                # scripted transient admission failure: bounded
-                # retry-with-backoff for everything admissible this round
-                deferred_n += self._defer_admissible(round_idx)
-            else:
-                while True:
-                    free = [s for s in slots
-                            if not s.active and s.index not in claimed]
-                    if not free:
-                        break
-                    r = self._pop_admissible(round_idx)
-                    if r is None:
-                        break
-                    if not paged and self._need(r) > max_seq:
-                        self._reject(r)
-                        continue
-                    # ---- prefix sharing: fork a live sibling's prompt
-                    # pages instead of re-prefilling the common prefix.
-                    # Re-admissions after preemption never share — their
-                    # resume stream diverges from every prompt — and a
-                    # tail longer than the prefill chunk takes the plain
-                    # chunked path instead of one oversized tail extend.
-                    leader, share_len = None, 0
-                    if prefix_ok and not r.resume_tokens:
-                        leader, share_len = self._find_leader(slots, r)
-                        if (leader is not None and eng.prefill_chunk
-                                and len(r.prompt) - share_len
-                                > eng.prefill_chunk):
-                            leader, share_len = None, 0
-                        if leader is None and self._should_stagger(
-                                r, batch_in, prefix_in, landed, chunking):
-                            r.arrival_round = round_idx + 1
-                            eng.queue.append(r)
-                            self._staggered.add(r.uid)
-                            deferred_n += 1
-                            self._count("prefix_staggered")
-                            continue
-                    if paged:
-                        while True:
-                            fresh = None
-                            if leader is not None:
-                                # private tail pages + the CoW boundary
-                                # page; the fork itself draws nothing
-                                fresh = (self._alloc.pages_for(
-                                    self._need(r))
-                                    - share_len // self._alloc.page_size)
-                            state, verdict = self._make_room(
-                                sess, state, r, chunking, round_idx,
-                                live_now, slots, fresh_pages=fresh)
-                            if leader is not None and not leader.active:
-                                # _make_room preempted the leader; its
-                                # pages are gone — re-budget unshared
-                                leader, share_len = None, 0
-                                continue
-                            break
-                        if verdict == "impossible":
-                            self._reject(r)
-                            continue
-                        if verdict == "defer":
-                            # backpressure applies to the whole boundary
-                            r.arrival_round = round_idx + 1
-                            eng.queue.append(r)
-                            deferred_n += 1
-                            self._count("admit_deferred")
-                            break
+                    while True:
                         free = [s for s in slots
                                 if not s.active and s.index not in claimed]
-                        row = free[0].index
+                        if not free:
+                            break
+                        r = self._pop_admissible(round_idx)
+                        if r is None:
+                            break
+                        if not paged and self._need(r) > max_seq:
+                            self._reject(r)
+                            continue
+                        # ---- prefix sharing: fork a live sibling's prompt
+                        # pages instead of re-prefilling the common prefix.
+                        # Re-admissions after preemption never share — their
+                        # resume stream diverges from every prompt — and a
+                        # tail longer than the prefill chunk takes the plain
+                        # chunked path instead of one oversized tail extend.
+                        leader, share_len = None, 0
+                        if prefix_ok and not r.resume_tokens:
+                            leader, share_len = self._find_leader(slots, r)
+                            if (leader is not None and eng.prefill_chunk
+                                    and len(r.prompt) - share_len
+                                    > eng.prefill_chunk):
+                                leader, share_len = None, 0
+                            if leader is None and self._should_stagger(
+                                    r, batch_in, prefix_in, landed, chunking):
+                                r.arrival_round = round_idx + 1
+                                eng.queue.append(r)
+                                self._staggered.add(r.uid)
+                                deferred_n += 1
+                                self._count("prefix_staggered")
+                                continue
+                        if paged:
+                            while True:
+                                fresh = None
+                                if leader is not None:
+                                    # private tail pages + the CoW boundary
+                                    # page; the fork itself draws nothing
+                                    fresh = (self._alloc.pages_for(
+                                        self._need(r))
+                                        - share_len // self._alloc.page_size)
+                                state, verdict = self._make_room(
+                                    sess, state, r, chunking, round_idx,
+                                    live_now, slots, fresh_pages=fresh)
+                                if leader is not None and not leader.active:
+                                    # _make_room preempted the leader; its
+                                    # pages are gone — re-budget unshared
+                                    leader, share_len = None, 0
+                                    continue
+                                break
+                            if verdict == "impossible":
+                                self._reject(r)
+                                continue
+                            if verdict == "defer":
+                                # backpressure applies to the whole boundary
+                                r.arrival_round = round_idx + 1
+                                eng.queue.append(r)
+                                deferred_n += 1
+                                self._count("admit_deferred")
+                                break
+                            free = [s for s in slots
+                                    if not s.active and s.index not in claimed]
+                            row = free[0].index
+                            if leader is not None:
+                                self._alloc.fork_prefix(leader.index, row,
+                                                        share_len)
+                                self._alloc.extend_row(row, self._need(r))
+                                pairs = self._alloc.cow_range(
+                                    row, share_len, self._need(r))
+                                cow_pairs.extend(pairs)
+                                shared_tok_n += share_len
+                                self._count("prefix_hits")
+                                self._count("prefix_shared_tokens", share_len)
+                                self._count("cow_copies", len(pairs))
+                            else:
+                                self._alloc.alloc(row, self._need(r))
+                            self._table_dirty = True
+                        s = free[0]
+                        claimed.add(s.index)
+                        if r.admitted_at is None:      # kept across requeues
+                            r.admitted_at = time.perf_counter()
+                        s.admit_seq = self._admit_seq
+                        self._admit_seq += 1
                         if leader is not None:
-                            self._alloc.fork_prefix(leader.index, row,
-                                                    share_len)
-                            self._alloc.extend_row(row, self._need(r))
-                            pairs = self._alloc.cow_range(
-                                row, share_len, self._need(r))
-                            cow_pairs.extend(pairs)
-                            shared_tok_n += share_len
-                            self._count("prefix_hits")
-                            self._count("prefix_shared_tokens", share_len)
-                            self._count("cow_copies", len(pairs))
-                        else:
-                            self._alloc.alloc(row, self._need(r))
-                        self._table_dirty = True
-                    s = free[0]
-                    claimed.add(s.index)
-                    s.admit_seq = self._admit_seq
-                    self._admit_seq += 1
-                    if leader is not None:
-                        prefix_in.append((s, r, share_len))
-                        continue
-                    toks = self._admit_toks(r)
-                    if eng.prefill_chunk and len(toks) > eng.prefill_chunk:
-                        chunking.append(_Chunking(
-                            s, r, sess.begin_admit_chunked(
-                                toks[None, :],
-                                np.array([len(toks)], np.int32),
-                                np.array([s.index], np.int32),
-                                chunk=eng.prefill_chunk,
-                                key=eng._next_key())))
-                        continue
-                    batch_in.append((s, r))
-            if self._table_dirty:
-                # one table upload covers every page assignment AND every
-                # preemption this round: a freed victim's row must point
-                # at trash page 0 before the next decode, or its frozen
-                # lane would write into pages the pool has re-issued
-                state = self._sync_table(state)
-            if cow_pairs:
-                # one bucketed device copy detaches every CoW boundary
-                # page this round; (0, 0) trash self-copies pad to pow2 so
-                # the copy dispatch stays shape-stable across rounds
-                n = _pow2_at_least(len(cow_pairs)) if eng.bucket_batches \
-                    else len(cow_pairs)
-                padded = cow_pairs + [(0, 0)] * (n - len(cow_pairs))
-                state = dc_replace(state, t_cache=copy_cache_pages(
-                    state.t_cache, padded))
-            if prefix_in:
-                state, rows_n, toks_n = self._admit_batch_prefix(
-                    sess, state, prefix_in)
-                admit_rows_n += rows_n
-                admit_tokens += toks_n
-                landed.extend((s, r) for s, r, _ in prefix_in)
-            if batch_in:
-                state, rows_n, toks_n = self._admit_batch(sess, state,
-                                                          batch_in)
-                admit_rows_n += rows_n
-                admit_tokens += toks_n
-                landed.extend(batch_in)
-            if landed:
-                first = jax.device_get(state.last_token)
-                for s, r in landed:
-                    s.request, s.active = r, True
-                    resume = list(r.resume_tokens or [])
-                    # a re-admission resumes the committed stream: the
-                    # recompute prefill already holds these tokens' KV,
-                    # and crediting them AGAIN would double-count across
-                    # the requeue — preload, don't re-append
-                    s.n_out, s.tokens = len(resume), resume
-                    if resume:
-                        r.readmit_round = round_idx
-                        r.resume_tokens = None
-                        self._count("requeues")
-                    # the admission prefill's sample is the first token
-                    admit_credited += self._append(s, [int(first[s.index])])
-            n_retired = sum(1 for s, r in landed if not s.active)
+                            prefix_in.append((s, r, share_len))
+                            continue
+                        toks = self._admit_toks(r)
+                        if eng.prefill_chunk and len(toks) > eng.prefill_chunk:
+                            chunking.append(_Chunking(
+                                s, r, sess.begin_admit_chunked(
+                                    toks[None, :],
+                                    np.array([len(toks)], np.int32),
+                                    np.array([s.index], np.int32),
+                                    chunk=eng.prefill_chunk,
+                                    key=eng._next_key())))
+                            continue
+                        batch_in.append((s, r))
+                if self._table_dirty:
+                    # one table upload covers every page assignment AND every
+                    # preemption this round: a freed victim's row must point
+                    # at trash page 0 before the next decode, or its frozen
+                    # lane would write into pages the pool has re-issued
+                    state = self._sync_table(state)
+                if cow_pairs:
+                    # one bucketed device copy detaches every CoW boundary
+                    # page this round; (0, 0) trash self-copies pad to pow2 so
+                    # the copy dispatch stays shape-stable across rounds
+                    n = _pow2_at_least(len(cow_pairs)) if eng.bucket_batches \
+                        else len(cow_pairs)
+                    padded = cow_pairs + [(0, 0)] * (n - len(cow_pairs))
+                    state = dc_replace(state, t_cache=copy_cache_pages(
+                        state.t_cache, padded))
+                if prefix_in:
+                    with self._admission("serve.admit"):
+                        state, rows_n, toks_n = self._admit_batch_prefix(
+                            sess, state, prefix_in)
+                    admit_rows_n += rows_n
+                    admit_tokens += toks_n
+                    landed.extend((s, r) for s, r, _ in prefix_in)
+                if batch_in:
+                    with self._admission("serve.admit"):
+                        state, rows_n, toks_n = self._admit_batch(
+                            sess, state, batch_in)
+                    admit_rows_n += rows_n
+                    admit_tokens += toks_n
+                    landed.extend(batch_in)
+                if landed:
+                    with self._admission("serve.admit_fetch"):
+                        first = jax.device_get(state.last_token)
+                    t_first = time.perf_counter()
+                    for s, r in landed:
+                        s.request, s.active = r, True
+                        if r.first_token_at is None:   # kept across requeues
+                            r.first_token_at = t_first
+                        resume = list(r.resume_tokens or [])
+                        # a re-admission resumes the committed stream: the
+                        # recompute prefill already holds these tokens' KV,
+                        # and crediting them AGAIN would double-count across
+                        # the requeue — preload, don't re-append
+                        s.n_out, s.tokens = len(resume), resume
+                        if resume:
+                            r.readmit_round = round_idx
+                            r.resume_tokens = None
+                            self._count("requeues")
+                        # the admission prefill's sample is the first token
+                        admit_credited += self._append(
+                            s, [int(first[s.index])])
+                n_retired = sum(1 for s, r in landed if not s.active)
 
-            active_mask = np.array([s.active for s in slots], bool)
-            live = int(active_mask.sum())
-            if live == 0:
-                if landed or admit_rows_n:
-                    # every admitted slot finished on its prefill token
-                    # (1-token budgets / instant eos) or only chunk work
-                    # ran: record the churn so steps never undercount
-                    steps.append(StepReport(round_idx, 0, 0, False,
-                                            admit_credited, len(landed),
-                                            n_retired, 0.0, admit_rows_n,
-                                            admit_tokens,
-                                            preempted=self._round_preempted,
-                                            deferred=deferred_n,
-                                            shared_tokens=shared_tok_n))
-                self._free_retired()
-                if not eng.queue and not chunking:
-                    break
-                if self._note_stall(had_admissible,
-                                    landed or admit_rows_n or n_retired):
-                    aborted = True
-                    self._abort(slots, chunking)
-                    break
-                round_idx += 1                  # idle: awaiting arrivals
-                continue
+                active_mask = np.array([s.active for s in slots], bool)
+                live = int(active_mask.sum())
+                if live == 0:
+                    if landed or admit_rows_n:
+                        # every admitted slot finished on its prefill token
+                        # (1-token budgets / instant eos) or only chunk work
+                        # ran: record the churn so steps never undercount
+                        steps.append(StepReport(
+                            round_idx, 0, 0, False, admit_credited,
+                            len(landed), n_retired, 0.0, admit_rows_n,
+                            admit_tokens, preempted=self._round_preempted,
+                            deferred=deferred_n, shared_tokens=shared_tok_n,
+                            admit_time=self._admit_s))
+                    self._free_retired()
+                    if not eng.queue and not chunking:
+                        break
+                    if self._note_stall(had_admissible,
+                                        landed or admit_rows_n or n_retired):
+                        aborted = True
+                        self._abort(slots, chunking)
+                        break
+                    round_idx += 1                  # idle: awaiting arrivals
+                    continue
 
-            # ---- re-plan on the LIVE slot count (the paper's N(t))
-            gamma, use_sd = eng.gamma, True
-            if eng.tuner is not None:
-                plan = eng.tuner.plan(live)
-                gamma, use_sd = plan["gamma"], plan["use_sd"]
-            if eng.force_sd is not None:
-                use_sd = eng.force_sd
-            if kind == "none":
-                use_sd = False
-            if self._forced_ar:
-                # degradation ladder rung 1: repeated faulty rounds force
-                # plain AR (gamma=0 in the SAME session) until a healthy
-                # round clears the cooldown — overrides even force_sd
-                use_sd = False
-            if not use_sd:
-                gamma = 0                       # in-session SD→AR handoff
-            if gamma > g_max:
-                # the cache margin was sized for g_max at stream start; a
-                # larger gamma would scatter verify KV past the allocated
-                # pages/rows, which JAX clamps SILENTLY — fail loudly
-                raise ValueError(
-                    f"tuner planned gamma={gamma} > g_max={g_max} the "
-                    "stream was sized for; expose the tuner's range via a "
-                    "'gammas' attribute (AutoTuner does)")
-            if first_gamma is None:
-                first_gamma = gamma
-            used_sd_any |= use_sd
+                # ---- re-plan on the LIVE slot count (the paper's N(t))
+                gamma, use_sd = eng.gamma, True
+                if eng.tuner is not None:
+                    plan = eng.tuner.plan(live)
+                    gamma, use_sd = plan["gamma"], plan["use_sd"]
+                if eng.force_sd is not None:
+                    use_sd = eng.force_sd
+                if kind == "none":
+                    use_sd = False
+                if self._forced_ar:
+                    # degradation ladder rung 1: repeated faulty rounds force
+                    # plain AR (gamma=0 in the SAME session) until a healthy
+                    # round clears the cooldown — overrides even force_sd
+                    use_sd = False
+                if not use_sd:
+                    gamma = 0                   # in-session SD→AR handoff
+                if gamma > g_max:
+                    # the cache margin was sized for g_max at stream start; a
+                    # larger gamma would scatter verify KV past the allocated
+                    # pages/rows, which JAX clamps SILENTLY — fail loudly
+                    raise ValueError(
+                        f"tuner planned gamma={gamma} > g_max={g_max} the "
+                        "stream was sized for; expose the tuner's range via a "
+                        "'gammas' attribute (AutoTuner does)")
+                if first_gamma is None:
+                    first_gamma = gamma
+                used_sd_any |= use_sd
 
-            # ---- scripted pre-round faults (testing only; inj is None in
-            # production streams)
-            t_r0 = time.perf_counter()
-            if inj is not None:
-                from repro.serving.faults import poison_cache_row
-                for f in inj.nan_rows(round_idx):
-                    row = f.row if f.row is not None else next(
-                        (s.index for s in slots if s.active), None)
-                    if row is not None:
-                        state = dc_replace(state, t_cache=poison_cache_row(
-                            state.t_cache, row))
-                delay = inj.slow_delay(round_idx)
-                if delay:
-                    time.sleep(delay)
+                # ---- scripted pre-round faults (testing only; inj is None in
+                # production streams)
+                t_r0 = time.perf_counter()
+                if inj is not None:
+                    from repro.serving.faults import poison_cache_row
+                    for f in inj.nan_rows(round_idx):
+                        row = f.row if f.row is not None else next(
+                            (s.index for s in slots if s.active), None)
+                        if row is not None:
+                            state = dc_replace(state, t_cache=poison_cache_row(
+                                state.t_cache, row))
+                    delay = inj.slow_delay(round_idx)
+                    if delay:
+                        time.sleep(delay)
+                round_key = eng._next_key()
 
             # ---- one SD round over the pool, retired rows masked out
-            state, res = sess.round(state, gamma=gamma, key=eng._next_key(),
+            state, res = sess.round(state, gamma=gamma, key=round_key,
                                     active=active_mask,
                                     timed=eng.timed)
             round_wall = time.perf_counter() - t_r0
 
-            # ---- numerical sentinel: quarantine non-finite rows before
-            # crediting (their n_commit is already forced to 0 in-round,
-            # so co-batched slots are untouched)
-            if res.finite is not None and not bool(np.all(res.finite)):
+            with jax.profiler.TraceAnnotation("serve.credit"):
+                # ---- numerical sentinel: quarantine non-finite rows before
+                # crediting (their n_commit is already forced to 0 in-round,
+                # so co-batched slots are untouched)
+                if res.finite is not None and not bool(np.all(res.finite)):
+                    for s in slots:
+                        if s.active and not bool(res.finite[s.index]):
+                            self._count("numerical_faults")
+                            self._finish(s, "numerical_fault")
+                            faults_n += 1
+                            n_retired += 1
+                credited = 0
                 for s in slots:
-                    if s.active and not bool(res.finite[s.index]):
-                        self._count("numerical_faults")
-                        self._finish(s, "numerical_fault")
-                        faults_n += 1
+                    if not s.active:
+                        continue
+                    n = int(res.n_commit[s.index])
+                    credited += self._append(
+                        s, list(res.committed[s.index, :n]))
+                    if not s.active:
                         n_retired += 1
-            credited = 0
-            for s in slots:
-                if not s.active:
-                    continue
-                n = int(res.n_commit[s.index])
-                credited += self._append(s, list(res.committed[s.index, :n]))
-                if not s.active:
-                    n_retired += 1
-            # ---- per-request round budgets
-            for s in slots:
-                if not s.active:
-                    continue
-                s.request.rounds_used += 1
-                if (rescfg.max_rounds_per_request is not None
-                        and s.request.rounds_used
-                        >= rescfg.max_rounds_per_request):
-                    self._count("timeouts")
-                    self._finish(s, "timeout")
-                    timeouts_n += 1
-                    n_retired += 1
-            self._free_retired()
+                # ---- per-request round budgets
+                for s in slots:
+                    if not s.active:
+                        continue
+                    s.request.rounds_used += 1
+                    if (rescfg.max_rounds_per_request is not None
+                            and s.request.rounds_used
+                            >= rescfg.max_rounds_per_request):
+                        self._count("timeouts")
+                        self._finish(s, "timeout")
+                        timeouts_n += 1
+                        n_retired += 1
+                self._free_retired()
 
-            # live-weighted accounting: retired rows' masked lanes commit
-            # nothing, so sigma/alpha describe the work actually requested
-            stats.absorb_round(res, live)
-            alpha_round = (float(res.n_accept.sum()) / (res.width * live)
-                           if (use_sd and res.width and live) else None)
-            if alpha_round is not None and eng.tuner is not None:
-                eng.tuner.update_alpha(alpha_round)
-            steps.append(StepReport(round_idx, live, gamma, use_sd,
-                                    admit_credited + credited,
-                                    len(landed), n_retired,
-                                    res.round_time, admit_rows_n,
-                                    admit_tokens,
-                                    preempted=self._round_preempted,
-                                    faults=faults_n, timeouts=timeouts_n,
-                                    deferred=deferred_n,
-                                    shared_tokens=shared_tok_n))
+                # live-weighted accounting: retired rows' masked lanes commit
+                # nothing, so sigma/alpha describe the work actually requested
+                stats.absorb_round(res, live)
+                alpha_round = (float(res.n_accept.sum()) / (res.width * live)
+                               if (use_sd and res.width and live) else None)
+                if alpha_round is not None and eng.tuner is not None:
+                    eng.tuner.update_alpha(alpha_round)
+                steps.append(StepReport(round_idx, live, gamma, use_sd,
+                                        admit_credited + credited,
+                                        len(landed), n_retired,
+                                        res.round_time, admit_rows_n,
+                                        admit_tokens,
+                                        preempted=self._round_preempted,
+                                        faults=faults_n, timeouts=timeouts_n,
+                                        deferred=deferred_n,
+                                        shared_tokens=shared_tok_n,
+                                        dispatch_time=res.dispatch_time,
+                                        admit_time=self._admit_s,
+                                        experts_reached=res.experts_reached))
 
-            # ---- degradation ladder: consecutive faulty rounds escalate
-            # healthy → forced AR → stream-level safe stop
-            slow = (rescfg.round_deadline_s is not None
-                    and round_wall > rescfg.round_deadline_s)
-            if slow:
-                self._count("slow_rounds")
-            collapsed = (rescfg.collapse_alpha > 0
-                         and alpha_round is not None
-                         and alpha_round < rescfg.collapse_alpha)
-            if faults_n or slow or collapsed:
-                self._consec_faulty += 1
-                if (not self._forced_ar and self._consec_faulty
-                        >= rescfg.faulty_rounds_to_ar):
-                    self._forced_ar = True
-                    self._count("ar_handoffs")
-                if self._consec_faulty >= rescfg.faulty_rounds_to_stop:
+                # ---- degradation ladder: consecutive faulty rounds escalate
+                # healthy → forced AR → stream-level safe stop
+                slow = (rescfg.round_deadline_s is not None
+                        and round_wall > rescfg.round_deadline_s)
+                if slow:
+                    self._count("slow_rounds")
+                collapsed = (rescfg.collapse_alpha > 0
+                             and alpha_round is not None
+                             and alpha_round < rescfg.collapse_alpha)
+                if faults_n or slow or collapsed:
+                    self._consec_faulty += 1
+                    if (not self._forced_ar and self._consec_faulty
+                            >= rescfg.faulty_rounds_to_ar):
+                        self._forced_ar = True
+                        self._count("ar_handoffs")
+                    if self._consec_faulty >= rescfg.faulty_rounds_to_stop:
+                        aborted = True
+                        self._abort(slots, chunking)
+                        break
+                else:
+                    self._consec_faulty = 0
+                    self._forced_ar = False
+                if self._note_stall(had_admissible,
+                                    admit_credited + credited or landed
+                                    or n_retired or admit_rows_n):
                     aborted = True
                     self._abort(slots, chunking)
                     break
-            else:
-                self._consec_faulty = 0
-                self._forced_ar = False
-            if self._note_stall(had_admissible,
-                                admit_credited + credited or landed
-                                or n_retired or admit_rows_n):
-                aborted = True
-                self._abort(slots, chunking)
-                break
             round_idx += 1
 
         if inj is not None and self._alloc is not None:

@@ -6,7 +6,7 @@ The contracts the prefetch subsystem rests on:
     baseline (the warm gather and the hit scoring are observation-only),
   * the router probe produces a static-shape PrefetchPlan (top-M experts
     per period-slot) and warm_experts gathers exactly those weights,
-  * hit/miss counts flow end to end: moe_forward → extend_with_prefetch →
+  * hit/miss counts flow end to end: moe_forward → Model.verify →
     SDStats → WaveReport → session_stats() aggregates,
   * `benchmarks/run --proposer prefetch` round-trips in dry mode (the lazy
     registry exposes the kind to argparse without importing the module).
@@ -25,7 +25,8 @@ from repro.core.proposer import make_proposer, registered_proposers
 from repro.core.spec_decode import SDEngine, generate_ar
 from repro.models.model import Model
 from repro.models.moe import (PrefetchPlan, init_moe, moe_forward,
-                              prefetch_hit_stats, warm_experts)
+                              prefetch_hit_stats, reached_experts,
+                              warm_experts)
 from repro.serving.engine import ServingEngine
 
 pytestmark = pytest.mark.tier1
@@ -107,8 +108,10 @@ def test_hit_stats_exact():
     assert int(m["prefetch_actual"]) == actual.sum()
     assert int(m["prefetch_hits"]) == (actual & np.asarray(mask)).sum()
     assert int(m["prefetch_predicted"]) == 4
+    # the one counter behind prefetch_actual and experts_reached
+    assert int(m["experts_reached"]) == actual.sum()
     # direct unit check of the scorer too
-    s = prefetch_hit_stats(mask, idx, cfg.num_experts)
+    s = prefetch_hit_stats(mask, reached_experts(idx, cfg.num_experts))
     assert int(s["prefetch_hits"]) == int(m["prefetch_hits"])
 
 
