@@ -23,6 +23,16 @@ accumulators; the activation and elementwise product are applied at tile
 emission.  Together with the down projection this makes the whole expert
 FFN 2 launches instead of 3, halving x HBM reads.
 
+Expert weights reach the kernel as one stack ``(P, E, d, f)`` (``P``
+layers) plus an int32 ``layer`` index, scalar-prefetched after the group
+metadata: the weight block's index map reads ``(layer, expert, 0, n-tile)``
+straight out of the stack.  A layer scan therefore hands the kernel the
+whole stacked array and its loop index, never a per-layer slice: XLA fuses
+a slice into its own dots, but a Mosaic call needs its operand as a buffer
+of its own, so a sliced weight would be copied whole (every expert of the
+layer) before each launch.  A plain ``(E, d, f)`` weight is the stack of
+one layer, index 0.
+
 Accumulation is fp32; outputs are cast back to the input dtype — the
 oracles in ref.py are the parity spec (see tests/test_ragged_gmm.py).
 """
@@ -100,8 +110,8 @@ def _visit_bookkeeping(offs, gids, mtids, nvis, *, bm: int):
     return first, valid, mask, mt
 
 
-def _ragged_kernel(offs, gids, mtids, nvis, x_ref, w_ref, o_ref, acc_ref,
-                   *, bm: int):
+def _ragged_kernel(offs, gids, mtids, nvis, layer, x_ref, w_ref, o_ref,
+                   acc_ref, *, bm: int):
     first, valid, mask, mt = _visit_bookkeeping(offs, gids, mtids, nvis, bm=bm)
 
     @pl.when(first)
@@ -111,7 +121,7 @@ def _ragged_kernel(offs, gids, mtids, nvis, x_ref, w_ref, o_ref, acc_ref,
     @pl.when(valid)
     def _accum():
         prod = jnp.dot(x_ref[...].astype(jnp.float32),
-                       w_ref[0].astype(jnp.float32),
+                       w_ref[0, 0].astype(jnp.float32),
                        preferred_element_type=jnp.float32)
         acc_ref[...] += jnp.where(mask, prod, 0.0)
 
@@ -120,8 +130,8 @@ def _ragged_kernel(offs, gids, mtids, nvis, x_ref, w_ref, o_ref, acc_ref,
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
-def _fused_kernel(offs, gids, mtids, nvis, x_ref, wg_ref, wu_ref, o_ref,
-                  acc_g, acc_u, *, bm: int, activation: str):
+def _fused_kernel(offs, gids, mtids, nvis, layer, x_ref, wg_ref, wu_ref,
+                  o_ref, acc_g, acc_u, *, bm: int, activation: str):
     first, valid, mask, mt = _visit_bookkeeping(offs, gids, mtids, nvis, bm=bm)
 
     @pl.when(first)
@@ -132,9 +142,9 @@ def _fused_kernel(offs, gids, mtids, nvis, x_ref, wg_ref, wu_ref, o_ref,
     @pl.when(valid)
     def _accum():
         x = x_ref[...].astype(jnp.float32)           # loaded once, used twice
-        pg = jnp.dot(x, wg_ref[0].astype(jnp.float32),
+        pg = jnp.dot(x, wg_ref[0, 0].astype(jnp.float32),
                      preferred_element_type=jnp.float32)
-        pu = jnp.dot(x, wu_ref[0].astype(jnp.float32),
+        pu = jnp.dot(x, wu_ref[0, 0].astype(jnp.float32),
                      preferred_element_type=jnp.float32)
         acc_g[...] += jnp.where(mask, pg, 0.0)
         acc_u[...] += jnp.where(mask, pu, 0.0)
@@ -149,11 +159,23 @@ def _fused_kernel(offs, gids, mtids, nvis, x_ref, wg_ref, wu_ref, o_ref,
 
 
 def _scalar_maps():
-    """Index maps for (x, w, out) blocks given the metadata scalar refs."""
-    x_map = lambda j, i, offs, gids, mtids, nvis: (mtids[i], 0)
-    w_map = lambda j, i, offs, gids, mtids, nvis: (gids[i], 0, j)
-    o_map = lambda j, i, offs, gids, mtids, nvis: (mtids[i], j)
+    """Index maps for (x, w, out) blocks given the scalar-prefetch refs
+    (group metadata, then the layer index into the weight stack)."""
+    x_map = lambda j, i, offs, gids, mtids, nvis, layer: (mtids[i], 0)
+    w_map = lambda j, i, offs, gids, mtids, nvis, layer: (layer[0], gids[i],
+                                                          0, j)
+    o_map = lambda j, i, offs, gids, mtids, nvis, layer: (mtids[i], j)
     return x_map, w_map, o_map
+
+
+def _stacked(ws, layer):
+    """``(ws as (P, E, d, f) stacks, (1,) int32 layer index)``: a 3-D
+    ``(E, d, f)`` weight is the stack of one layer, index 0."""
+    if ws[0].ndim == 3:
+        return tuple(w[None] for w in ws), jnp.zeros((1,), jnp.int32)
+    if layer is None:
+        raise TypeError("stacked (P, E, d, f) weights need a layer index")
+    return ws, jnp.reshape(layer, (1,)).astype(jnp.int32)
 
 
 def _row_tile(n: int, bm: int, dtype) -> int:
@@ -161,42 +183,46 @@ def _row_tile(n: int, bm: int, dtype) -> int:
     return min(bm, max(sub, _round_up(n, sub)))
 
 
-def _ragged_call(xs_pad, ws, meta: GroupMetadata, kernel, n_acc: int,
+def _ragged_call(xs_pad, ws, layer, meta: GroupMetadata, kernel, n_acc: int,
                  out_f: int, *, bm: int, bn: int, interpret: bool):
-    """Shared pallas_call plumbing for the single and fused kernels."""
+    """Shared pallas_call plumbing for the single and fused kernels: ``ws``
+    are ``(P, E, d, f)`` stacks, ``layer`` the (1,) index into them."""
     n_pad, d = xs_pad.shape
-    E = ws[0].shape[0]
+    E = ws[0].shape[1]
     t_max = n_pad // bm + E - 1
     x_map, w_map, o_map = _scalar_maps()
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=5,
             grid=(out_f // bn, t_max),
             in_specs=[pl.BlockSpec((bm, d), x_map)]
-            + [pl.BlockSpec((1, d, bn), w_map) for _ in ws],
+            + [pl.BlockSpec((1, 1, d, bn), w_map) for _ in ws],
             out_specs=pl.BlockSpec((bm, bn), o_map),
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)] * n_acc,
         ),
         out_shape=jax.ShapeDtypeStruct((n_pad, out_f), xs_pad.dtype),
         interpret=interpret,
-    )(*meta, xs_pad, *ws)
+    )(*meta, layer, xs_pad, *ws)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret"))
 def ragged_gmm(xs: jnp.ndarray,           # (N, D) tokens sorted by expert
-               w: jnp.ndarray,            # (E, D, F) expert weights
+               w: jnp.ndarray,            # (E, D, F) or (P, E, D, F)
                group_sizes: jnp.ndarray,  # (E,) rows per expert
-               *, bm: int = 128, bn: int = 128,
+               *, layer: Optional[jnp.ndarray] = None,
+               bm: int = 128, bn: int = 128,
                interpret: Optional[bool] = None) -> jnp.ndarray:   # (N, F)
+    """xs @ w[g] per expert group; a stacked ``w`` is read at ``layer``."""
+    (w,), layer = _stacked((w,), layer)
     N, D = xs.shape
-    E, _, F = w.shape
+    F = w.shape[-1]
     bm = _row_tile(N, bm, xs.dtype)
     bn = _pick_tile(F, bn)
     n_pad = _round_up(N, bm)
     xs_pad = jnp.pad(xs, ((0, n_pad - N), (0, 0)))
     meta = make_group_metadata(group_sizes, n_pad, bm)
-    out = _ragged_call(xs_pad, (w,), meta,
+    out = _ragged_call(xs_pad, (w,), layer, meta,
                        functools.partial(_ragged_kernel, bm=bm),
                        1, F, bm=bm, bn=bn,
                        interpret=resolve_interpret(interpret))
@@ -206,19 +232,22 @@ def ragged_gmm(xs: jnp.ndarray,           # (N, D) tokens sorted by expert
 @functools.partial(jax.jit,
                    static_argnames=("activation", "bm", "bn", "interpret"))
 def fused_gate_up(xs: jnp.ndarray, w_gate: jnp.ndarray, w_up: jnp.ndarray,
-                  group_sizes: jnp.ndarray, *, activation: str = "silu",
-                  bm: int = 128, bn: int = 128,
+                  group_sizes: jnp.ndarray, *,
+                  layer: Optional[jnp.ndarray] = None,
+                  activation: str = "silu", bm: int = 128, bn: int = 128,
                   interpret: Optional[bool] = None) -> jnp.ndarray:
-    """act(xs @ w_gate[g]) * (xs @ w_up[g]) in ONE launch: (N, D) → (N, F)."""
+    """act(xs @ w_gate[g]) * (xs @ w_up[g]) in ONE launch: (N, D) → (N, F).
+    Stacked ``(P, E, D, F)`` weights are read at ``layer``."""
+    (w_gate, w_up), layer = _stacked((w_gate, w_up), layer)
     N, D = xs.shape
-    E, _, F = w_gate.shape
+    F = w_gate.shape[-1]
     bm = _row_tile(N, bm, xs.dtype)
     bn = _pick_tile(F, bn)
     n_pad = _round_up(N, bm)
     xs_pad = jnp.pad(xs, ((0, n_pad - N), (0, 0)))
     meta = make_group_metadata(group_sizes, n_pad, bm)
     out = _ragged_call(
-        xs_pad, (w_gate, w_up), meta,
+        xs_pad, (w_gate, w_up), layer, meta,
         functools.partial(_fused_kernel, bm=bm, activation=activation),
         2, F, bm=bm, bn=bn, interpret=resolve_interpret(interpret))
     return out[:N]
@@ -227,25 +256,29 @@ def fused_gate_up(xs: jnp.ndarray, w_gate: jnp.ndarray, w_up: jnp.ndarray,
 @functools.partial(jax.jit,
                    static_argnames=("activation", "bm", "bn", "interpret"))
 def ragged_moe_ffn(xs: jnp.ndarray,       # (N, D) tokens sorted by expert
-                   w_gate: jnp.ndarray, w_up: jnp.ndarray,
-                   w_down: jnp.ndarray,   # (E, F, D)
-                   group_sizes: jnp.ndarray, *, activation: str = "silu",
-                   bm: int = 128, bn: int = 128,
+                   w_gate: jnp.ndarray, w_up: jnp.ndarray,  # ([P,] E, D, F)
+                   w_down: jnp.ndarray,   # ([P,] E, F, D)
+                   group_sizes: jnp.ndarray, *,
+                   layer: Optional[jnp.ndarray] = None,
+                   activation: str = "silu", bm: int = 128, bn: int = 128,
                    interpret: Optional[bool] = None) -> jnp.ndarray:
     """Whole expert FFN on expert-sorted tokens in 2 launches (fused gate+up,
-    then down).  Group metadata is built once and shared."""
+    then down).  Group metadata is built once and shared.  Stacked
+    ``(P, E, ...)`` weights are read at ``layer`` inside the kernels, so a
+    layer scan passes the stacks and its index, never a slice."""
+    (w_gate, w_up, w_down), layer = _stacked((w_gate, w_up, w_down), layer)
     N, D = xs.shape
-    E, _, F = w_gate.shape
+    F = w_gate.shape[-1]
     bm = _row_tile(N, bm, xs.dtype)
     n_pad = _round_up(N, bm)
     xs_pad = jnp.pad(xs, ((0, n_pad - N), (0, 0)))
     meta = make_group_metadata(group_sizes, n_pad, bm)
     interpret = resolve_interpret(interpret)
     h = _ragged_call(
-        xs_pad, (w_gate, w_up), meta,
+        xs_pad, (w_gate, w_up), layer, meta,
         functools.partial(_fused_kernel, bm=bm, activation=activation),
         2, F, bm=bm, bn=_pick_tile(F, bn), interpret=interpret)
-    y = _ragged_call(h, (w_down,), meta,
+    y = _ragged_call(h, (w_down,), layer, meta,
                      functools.partial(_ragged_kernel, bm=bm),
                      1, D, bm=bm, bn=_pick_tile(D, bn), interpret=interpret)
     return y[:N]

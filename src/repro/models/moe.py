@@ -222,11 +222,12 @@ def _dispatch_onehot(params, cfg, x, weights, indices):
     return jnp.einsum("end,ne->nd", y, combine.astype(y.dtype))
 
 
-def _dispatch_gmm(params, cfg, x, weights, indices, group_sizes):
+def _dispatch_gmm(params, cfg, x, weights, indices, group_sizes, layer=None):
     """Sort tokens by expert, ragged grouped matmul (kernels/gmm/ragged.py):
     fused gate+up launch then down launch — 2 Pallas calls per MoE FFN, and
     expert GEMM work scales with the routed token count N*K, not E*C.
-    ``group_sizes`` (E,) is the routed-row count per expert."""
+    ``group_sizes`` (E,) is the routed-row count per expert; ``layer``
+    indexes stacked ``(P, E, ...)`` expert weights inside the kernels."""
     from repro.kernels.gmm import ops as gmm_ops
 
     N, d = x.shape
@@ -238,7 +239,7 @@ def _dispatch_gmm(params, cfg, x, weights, indices, group_sizes):
 
     ys = gmm_ops.ragged_moe_ffn(
         xs, params["w_gate"], params["w_up"], params["w_down"], group_sizes,
-        activation=cfg.mlp_activation)                      # (N*K, d)
+        layer=layer, activation=cfg.mlp_activation)         # (N*K, d)
 
     w_flat = weights.reshape(-1)[order].astype(ys.dtype)    # (N*K,)
     out = jnp.zeros((N, d), ys.dtype)
@@ -256,6 +257,7 @@ def moe_forward(
     prefetch_mask: Optional[jnp.ndarray] = None,   # (E,) predicted-hot experts
     mesh=None,
     mesh_layout: Optional[str] = None,
+    layer: Optional[jnp.ndarray] = None,
 ):
     """Routed MoE FFN: top-k route, dispatch to experts, weighted combine.
 
@@ -285,6 +287,11 @@ def moe_forward(
         Device mesh (and layout) threaded explicitly to the "ep" dispatch
         and ignored by the single-device dispatches — see
         docs/distributed.md.
+    layer : jnp.ndarray, optional
+        "gmm" only: the int32 index of this layer when ``w_gate``/``w_up``/
+        ``w_down`` are the whole ``(P, E, ...)`` layer stacks, which the
+        kernels then read at that index (no per-layer slice).  None for
+        per-layer ``(E, ...)`` weights.
 
     Returns
     -------
@@ -326,7 +333,7 @@ def moe_forward(
         group_sizes = jnp.bincount(indices.reshape(-1),
                                    length=cfg.num_experts)
         y = _dispatch_gmm(params, cfg, xf, weights.astype(x.dtype), indices,
-                          group_sizes)
+                          group_sizes, layer)
         reached = group_sizes > 0
     else:
         y = _dispatch_onehot(params, cfg, xf, weights.astype(x.dtype), indices)

@@ -87,7 +87,11 @@ def block_forward(
     paged_attention: str = "kernel",
     mesh=None,
     mesh_layout: Optional[str] = None,
+    layer: Optional[jnp.ndarray] = None,
 ) -> Tuple[jnp.ndarray, Optional[dict], dict]:
+    """One block.  ``layer`` (gmm dispatch) is the block's index into the
+    whole ``(P, E, ...)`` expert stacks that ``params["ffn"]`` then holds
+    (``stack_forward``); None where the experts are this layer's own."""
     h = apply_norm(params["norm1"], x, cfg.norm_eps)
     if kind in ("attn", "swa"):
         out, new_cache = attn.gqa_forward(
@@ -129,7 +133,8 @@ def block_forward(
             y, m = moe_mod.moe_forward(params["ffn"], cfg, h, dispatch=dispatch,
                                        return_metrics=want_metrics,
                                        prefetch_mask=prefetch_mask,
-                                       mesh=mesh, mesh_layout=mesh_layout)
+                                       mesh=mesh, mesh_layout=mesh_layout,
+                                       layer=layer)
             metrics.update(m or {})
         else:
             y = apply_mlp(params["ffn"], h, cfg.mlp_activation)
@@ -212,17 +217,34 @@ def stack_forward(
     ``mesh``/``mesh_layout`` (optional) thread the device mesh down to the
     sharding constraints and the expert-parallel dispatch
     (docs/distributed.md) — no process-global mesh state.
+
+    With the "gmm" dispatch the routed experts' ``w_gate``/``w_up``/
+    ``w_down`` are scan constants and the period index is scanned: the
+    ragged kernels read each layer's experts out of the whole ``(P, E,
+    ...)`` stacks (kernels/gmm/ragged.py), where a scanned slice would be
+    copied whole before every launch.
     """
+    experts = [None] * cfg.period
+    if dispatch == "gmm":
+        layer_params = list(layer_params)
+        for i, is_moe in enumerate(cfg.moe_pattern):
+            if is_moe and "ffn" in layer_params[i]:
+                ffn = dict(layer_params[i]["ffn"])
+                experts[i] = {k: ffn.pop(k)
+                              for k in ("w_gate", "w_up", "w_down")}
+                layer_params[i] = {**layer_params[i], "ffn": ffn}
+    periods = (jnp.arange(cfg.num_periods)
+               if any(e is not None for e in experts) else None)
 
     def make_block(i, kind, is_moe):
-        def blk(lp_i, h, lc_i, lx_i, lm_i):
+        def blk(lp_i, h, lc_i, lx_i, lm_i, layer):
             return block_forward(
                 lp_i, cfg, kind, is_moe, h, positions, lc_i,
                 mode=mode, collect=collect, causal=causal, dispatch=dispatch,
                 want_metrics=want_metrics, use_flash=use_flash, cross_kv=lx_i,
                 mrope_positions=mrope_positions, prefetch_mask=lm_i,
                 page_table=page_table, paged_attention=paged_attention,
-                mesh=mesh, mesh_layout=mesh_layout)
+                mesh=mesh, mesh_layout=mesh_layout, layer=layer)
         # per-LAYER rematerialization: checkpointing the whole period keeps
         # every layer's FFN/attention intermediates live during the period's
         # backward (107 GB/device on jamba train_4k — §Perf C4); per-layer
@@ -234,21 +256,25 @@ def stack_forward(
               in enumerate(zip(cfg.layer_pattern, cfg.moe_pattern))]
 
     def period_fn(h, scanned):
-        lp, lc, lx, lm = scanned
+        lp, lc, lx, lm, p = scanned
         new_caches = []
         agg = None
         for i in range(cfg.period):
+            lp_i, layer = lp[i], None
+            if experts[i] is not None:
+                lp_i = {**lp_i, "ffn": {**lp_i["ffn"], **experts[i]}}
+                layer = p
             h, nc, m = blocks[i](
-                lp[i], h,
+                lp_i, h,
                 None if lc is None else lc[i],
                 None if lx is None else lx[i],
-                None if lm is None else lm[i])
+                None if lm is None else lm[i], layer)
             new_caches.append(nc if nc is not None else {})
             agg = m if agg is None else jax.tree.map(jnp.add, agg, m)
         return constrain(h, "hidden", mesh=mesh, layout=mesh_layout), \
             (new_caches, agg)
 
-    xs = (layer_params, caches, cross_kvs, prefetch_masks)
+    xs = (layer_params, caches, cross_kvs, prefetch_masks, periods)
 
     def scan_body(h, scanned):
         return period_fn(h, scanned)

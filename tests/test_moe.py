@@ -74,3 +74,81 @@ def test_shared_experts_add():
     p2.pop("shared")
     y2, _ = moe_forward(p2, cfg, x)
     assert float(jnp.abs(y - y2).max()) > 1e-6
+
+
+# ------------------------------------------- stack_forward's gmm scan path
+
+_EXPERT_KEYS = ("w_gate", "w_up", "w_down")
+
+
+def _stack_case(moe_pattern):
+    """A two-period stack (period = len(moe_pattern)), its params and a
+    (2, 5, d) input."""
+    from repro.models.transformer import init_stack
+    cfg = CFG.with_overrides(num_layers=2 * len(moe_pattern),
+                             layer_pattern=("attn",) * len(moe_pattern),
+                             moe_pattern=moe_pattern)
+    lp = init_stack(jax.random.PRNGKey(6), cfg, jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(7), (2, 5, 64)) * 0.5
+    pos = jnp.broadcast_to(jnp.arange(5), (2, 5))
+    return cfg, lp, x, pos
+
+
+def _stack(cfg, dispatch, pos):
+    from repro.models.transformer import stack_forward
+    return lambda lp, x: stack_forward(lp, cfg, x, pos, None, mode="train",
+                                       dispatch=dispatch,
+                                       want_metrics=False)[0]
+
+
+@pytest.mark.parametrize("moe_pattern", [(True,), (True, False)])
+def test_stack_forward_gmm_matches_layer_by_layer(moe_pattern):
+    """The scan that reads each layer's experts out of the whole stacks
+    gives exactly what block_forward gives layer by layer on sliced
+    params."""
+    from repro.models.transformer import block_forward
+    cfg, lp, x, pos = _stack_case(moe_pattern)
+
+    def by_layer(lp, h):
+        for p in range(cfg.num_periods):
+            for i, is_moe in enumerate(cfg.moe_pattern):
+                h, _, _ = block_forward(
+                    jax.tree.map(lambda a: a[p], lp[i]), cfg, "attn", is_moe,
+                    h, pos, None, mode="train", dispatch="gmm",
+                    want_metrics=False)
+        return h
+
+    got = jax.jit(_stack(cfg, "gmm", pos))(lp, x)
+    want = jax.jit(by_layer)(lp, x)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _scan_inputs(fn, lp, x):
+    """(constants, scanned inputs) of the single scan in fn's jaxpr."""
+    eqns = [e for e in jax.make_jaxpr(fn)(lp, x).jaxpr.eqns
+            if e.primitive.name == "scan"]
+    assert len(eqns) == 1
+    e = eqns[0]
+    n_const, n_carry = e.params["num_consts"], e.params["num_carry"]
+    shapes = lambda vs: [(v.aval.shape, v.aval.dtype) for v in vs]
+    return (shapes(e.invars[:n_const]),
+            shapes(e.invars[n_const + n_carry:]))
+
+
+@pytest.mark.parametrize("dispatch", ["gmm", "onehot"])
+def test_stack_forward_scans_expert_stacks_only_for_onehot(dispatch):
+    """Under gmm the (P, E, d, f) expert stacks are scan constants and the
+    period index is scanned; under onehot they are scanned as before and
+    no index is added."""
+    cfg, lp, x, pos = _stack_case((True,))
+    experts = [(lp[0]["ffn"][k].shape, lp[0]["ffn"][k].dtype)
+               for k in _EXPERT_KEYS]
+    consts, scanned = _scan_inputs(_stack(cfg, dispatch, pos), lp, x)
+    index = ((cfg.num_periods,), jnp.dtype(jnp.int32))
+    if dispatch == "gmm":
+        assert not any(s in scanned for s in experts)
+        assert all(s in consts for s in experts)
+        assert index in scanned
+    else:
+        assert all(s in scanned for s in experts)
+        assert index not in scanned

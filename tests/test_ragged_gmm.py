@@ -75,6 +75,61 @@ def test_ragged_moe_ffn_matches_ref():
                                atol=2e-4)
 
 
+def _stacked_case(P=3, D=64, F=96, seed=0):
+    """P layers of (E, D, F)/(E, F, D) experts stacked on a leading axis."""
+    sizes = jnp.asarray([37, 0, 1, 129, 0, 77, 13, 200], jnp.int32)
+    E, N = sizes.shape[0], int(sizes.sum())
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    xs = jax.random.normal(ks[0], (N, D))
+    wg = jax.random.normal(ks[1], (P, E, D, F)) / np.sqrt(D)
+    wu = jax.random.normal(ks[2], (P, E, D, F)) / np.sqrt(D)
+    wd = jax.random.normal(ks[3], (P, E, F, D)) / np.sqrt(F)
+    return sizes, xs, wg, wu, wd
+
+
+# each entry point on (xs, wg, wu, wd, sizes): the weights it takes, and
+# its ref.py oracle on per-layer (E, ...) weights
+_ENTRY = {
+    "ragged_gmm": (ragged_gmm, lambda wg, wu, wd: (wg,),
+                   lambda xs, w, s: ragged_gmm_ref(xs, *w, s), 1e-4),
+    "fused_gate_up": (fused_gate_up, lambda wg, wu, wd: (wg, wu),
+                      lambda xs, w, s: fused_gate_up_ref(xs, *w, s), 1e-4),
+    "ragged_moe_ffn": (ragged_moe_ffn, lambda wg, wu, wd: (wg, wu, wd),
+                       lambda xs, w, s: ragged_moe_ffn_ref(xs, *w, s), 2e-4),
+}
+
+
+@pytest.mark.parametrize("entry", sorted(_ENTRY))
+@pytest.mark.parametrize("layer", [0, 1, 2])
+def test_stacked_weights_read_at_layer(entry, layer):
+    """Stacked (P, E, ...) weights and a layer index give exactly the call
+    on that layer's (E, ...) slice, and still match the oracle."""
+    fn, pick, ref, tol = _ENTRY[entry]
+    sizes, xs, *ws = _stacked_case()
+    stacked = pick(*ws)
+    sliced = tuple(w[layer] for w in stacked)
+    got = fn(xs, *stacked, sizes, layer=jnp.int32(layer), interpret=True)
+    want = fn(xs, *sliced, sizes, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(ref(xs, sliced, sizes)),
+                               rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("entry", sorted(_ENTRY))
+def test_unstacked_weights_are_one_layer_stack(entry):
+    """A 3-D (E, ...) weight needs no layer and is the stack of one layer at
+    index 0; a stack without a layer index is refused."""
+    fn, pick, _, _ = _ENTRY[entry]
+    sizes, xs, *ws = _stacked_case(P=1)
+    stacked = pick(*ws)
+    plain = fn(xs, *(w[0] for w in stacked), sizes, interpret=True)
+    one = fn(xs, *stacked, sizes, layer=0, interpret=True)
+    np.testing.assert_array_equal(np.asarray(plain), np.asarray(one))
+    with pytest.raises(TypeError, match="layer index"):
+        fn(xs, *stacked, sizes, interpret=True)
+
+
 @pytest.mark.parametrize("K", [1, 2, 8])
 def test_moe_forward_gmm_parity_across_topk(K):
     """Full routed-FFN parity through moe_forward for K in {1, 2, 8}."""

@@ -11,6 +11,8 @@ fits the chip.  Nothing runs, so nothing here is a timing.
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -18,8 +20,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.configs.base import ModelConfig
 from repro.kernels.decode_attention.ops import paged_decode_attention
+from repro.kernels.gmm import ragged
 from repro.kernels.gmm.ragged import ragged_moe_ffn
+from repro.models.transformer import init_stack, stack_forward
 
 V5E_HBM = 16 * 10**9                  # bytes per chip (Google Cloud, "TPU v5e")
 KERNEL_TEMP = 64 * 2**20              # scratch a kernel program may add
@@ -63,6 +68,39 @@ def test_ragged_moe_ffn_compiles(one_chip, d, f, e, k):
         s((rows, d), bf), s((e, d, f), bf), s((e, d, f), bf),
         s((e, f, d), bf), s((e,), jnp.int32))
     assert mem.argument_size_in_bytes >= 3 * e * d * f * 2
+
+
+def test_layer_scan_passes_expert_stacks_to_gmm(one_chip, monkeypatch):
+    """Two qwen2-57b-a14b layers through stack_forward's gmm path, one
+    verify pass of 16 rows x gamma+1=5 (640 routed rows): the kernels read
+    each layer's experts out of the (P, E, d, f) stacks, so no layer's
+    bf16[64, ...] expert slab is sliced into a buffer of its own (1.17 GB
+    each, 2.4 GB of temporaries when the scan hands the kernel a slice)."""
+    monkeypatch.setattr(ragged, "resolve_interpret", lambda i=None: False)
+    ragged_moe_ffn.clear_cache()      # no trace made for the CPU is reused
+    cfg = ModelConfig("qwen2-57b-a14b", "moe", 2, 3584, 28, 4, 2560, 151936,
+                      head_dim=128, num_experts=64, num_experts_per_tok=8,
+                      moe_d_ff=2560, num_shared_experts=8, qkv_bias=True,
+                      dtype="bfloat16")
+    s = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    lp = jax.tree.map(s, jax.eval_shape(
+        lambda: init_stack(jax.random.PRNGKey(0), cfg, jnp.bfloat16)))
+    x = s(jax.ShapeDtypeStruct((16, 5, 3584), jnp.bfloat16))
+    pos = s(jax.ShapeDtypeStruct((16, 5), jnp.int32))
+    compiled = jax.jit(lambda lp, x, pos: stack_forward(
+        lp, cfg, x, pos, None, mode="train", dispatch="gmm",
+        want_metrics=False)[0]).lower(lp, x, pos).compile()
+    ragged_moe_ffn.clear_cache()
+    text = compiled.as_text()
+    # the launches keep the name the trace's gmm readers select
+    assert len(re.findall(r"%ragged_moe_ffn\.\d+ = \S+ custom-call\(",
+                          text)) >= 2
+    slabs = [ln for ln in text.splitlines()
+             if re.search(r"= bf16\[64,\S* fusion\(", ln)
+             and "dynamic-slice" in ln]
+    assert not slabs, slabs[0][:200]
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes <= KERNEL_TEMP, mem
 
 
 @pytest.mark.parametrize("hq,hkv", [(28, 4), (32, 8)])
